@@ -91,15 +91,14 @@ def run_jax(rows: list[Row] | None = None) -> list[Row]:
     histogram-accumulate kernel (interpret mode off-TPU), its XLA
     fallback, and the host-side NumPy bucketize.
     """
-    rows = [] if rows is None else rows
-    try:
-        import jax
-        from repro.fleet.engine_jax import simulate_jobs_jax
-        from repro.kernels.fleet_hist import _interpret, ofu_bucket_hist
-    except Exception as e:  # pragma: no cover — env without jax
-        print(f"BENCH-SKIP fleet_engine_jax ({type(e).__name__}: {e})")
-        return rows
+    import jax
+
     from repro.fleet.engine import JobSlot, simulate_jobs_fused
+    from repro.fleet.engine_jax import simulate_jobs_jax
+    from repro.kernels.fleet_hist import ofu_bucket_hist
+    from repro.kernels.ops import _interpret
+
+    rows = [] if rows is None else rows
 
     n_dev = int(os.environ.get("FLEET_JAX_DEVICES", "100000"))
     hours = float(os.environ.get("FLEET_JAX_HOURS", "1"))

@@ -15,6 +15,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (clock_sampling, fleet_engine, operational,
                             precision_scaling, prediction_accuracy,
                             production_correlation, roofline,

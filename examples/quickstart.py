@@ -35,6 +35,7 @@ import sys
 sys.path.insert(0, "src")
 
 from repro.configs.base import ShapeSpec, get_config
+from repro.core.peaks import TPU_V5E
 from repro.flops.accounting import step_flops
 from repro.optim import adamw
 from repro.train.trainer import TrainConfig, Trainer
@@ -59,7 +60,8 @@ def main():
         opt_cfg=adamw.OptConfig(peak_lr=1e-3, warmup_steps=5,
                                 decay_steps=args.steps),
         train_cfg=TrainConfig(total_steps=args.steps, ckpt_every=10,
-                              ckpt_dir=args.ckpt_dir, log_every=5),
+                              ckpt_dir=args.ckpt_dir, log_every=5,
+                              chip=TPU_V5E),
         flops_per_step=step_flops(cfg, shape, executed=True).total)
     out = trainer.run()
 

@@ -37,8 +37,8 @@ class ChipSpec:
         return base * self.precision_mult.get(dtype, 1.0)
 
 
-# TPU v5e: 197 TFLOP/s bf16, 394 TOPS int8 (published); 819 GB/s HBM;
-# ~50 GB/s/link ICI (per the assignment's hardware constants).
+# TPU v5e, per Google Cloud's "TPU v5e" page: 197 TFLOP/s bf16, 394 TOP/s
+# int8, 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of ICI (4 links x 50 GB/s).
 TPU_V5E = ChipSpec(
     name="tpu-v5e",
     num_mxu=4, mxu_rows=128, mxu_cols=128, flops_per_macc=2,
@@ -71,4 +71,27 @@ TPU_V6E_LIKE = ChipSpec(
 )
 
 CHIPS = {c.name: c for c in (TPU_V5E, TPU_V6E_LIKE)}
+#: the chip the fleet simulator models when a job names none
 DEFAULT_CHIP = TPU_V5E
+
+#: real devices by `jax.Device.device_kind` (sources as on each spec)
+CHIPS_BY_KIND = {"TPU v5 lite": TPU_V5E}
+
+
+def chip_for_kind(device_kind: str) -> ChipSpec:
+    """The spec of a device as JAX names it; an unknown kind raises
+    rather than borrowing another chip's peaks."""
+    try:
+        return CHIPS_BY_KIND[device_kind]
+    except KeyError:
+        raise ValueError(f"no peak table entry for device_kind "
+                         f"{device_kind!r} (known: "
+                         f"{sorted(CHIPS_BY_KIND)})") from None
+
+
+def device_chip(device=None) -> ChipSpec:
+    """The spec of `device` (default: the first JAX device)."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    return chip_for_kind(device.device_kind)

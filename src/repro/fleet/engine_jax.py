@@ -50,10 +50,13 @@ def default_mesh() -> Optional[jax.sharding.Mesh]:
 
 
 def _shard(x, mesh):
-    """Constrain rows (devices) across the mesh; skipped when rows do not
-    divide the mesh (jit lowering rejects uneven shards)."""
-    if mesh is None or x.shape[0] % mesh.size:
+    """Constrain rows (devices) across the mesh.  Rows must divide the
+    mesh: `_simulate_group_jax` pads them so they do."""
+    if mesh is None:
         return x
+    if x.shape[0] % mesh.size:
+        raise ValueError(f"{x.shape[0]} grid rows do not divide the "
+                         f"{mesh.size}-device mesh")
     spec = jax.sharding.PartitionSpec("devices",
                                       *([None] * (x.ndim - 1)))
     return jax.lax.with_sharding_constraint(
@@ -103,24 +106,47 @@ def _group_device_sim(ratio, strag, dev_job, sig, ev_base, ev_rows,
     return tpa, _shard(f.T, mesh)
 
 
-def _simulate_group_jax(members, out, rng, params, mesh, materialize):
-    """Host half: mirrors `engine._simulate_group`'s prep (same event
-    factors, same n_eff/n_sub policy), then hands one jitted call the
-    per-group arrays."""
+def _group_dims(members):
+    """(interval, per-member straggler factors, per-member sample counts)."""
     interval = float(members[0][1].interval_s)
-    cm = members[0][2]
     strag_list = [np.ones(1) if sl.stragglers is None
                   else np.atleast_1d(np.asarray(sl.stragglers, float))
                   for _, sl, _ in members]
-    n_dev = np.array([len(s) for s in strag_list])
     S = np.array([max(int(sl.duration_s / interval), 0)
                   for _, sl, _ in members])
-    S_max = int(S.max())
-    if S_max <= 0:
+    return interval, strag_list, S
+
+
+def _simulate_group_jax(members, out, rng, params, mesh, materialize):
+    """One fused group: host prep, one jitted device call, then a
+    DeviceGrid per member sliced from the group's rows."""
+    interval, strag_list, S = _group_dims(members)
+    if int(S.max()) <= 0:
         for (i, _, _), st in zip(members, strag_list):
             out[i] = DeviceGrid(interval, np.empty((len(st), 0)),
                                 np.empty((len(st), 0)))
         return
+    args, static = _group_inputs(members, rng, params, mesh)
+    tpa, clock = _group_device_sim(*(jnp.asarray(a) for a in args),
+                                   **static)
+    row0 = 0
+    for (i, _, _), st, Sj in zip(members, strag_list, S):
+        nd = len(st)
+        t, c = tpa[row0:row0 + nd, :Sj], clock[row0:row0 + nd, :Sj]
+        if materialize:
+            t, c = np.asarray(t), np.asarray(c)
+        out[i] = DeviceGrid(interval, t, c)
+        row0 += nd
+
+
+def _group_inputs(members, rng, params, mesh):
+    """Host half: mirrors `engine._simulate_group`'s prep (same event
+    factors, same n_eff/n_sub policy).  Returns the positional host
+    arrays and the static keywords of one `_group_device_sim` call."""
+    interval, strag_list, S = _group_dims(members)
+    S_max = int(S.max())
+    cm = members[0][2]
+    n_dev = np.array([len(s) for s in strag_list])
     avg_w = check_scrape_interval(interval, strict=False)
 
     J = len(members)
@@ -131,6 +157,11 @@ def _simulate_group_jax(members, out, rng, params, mesh, materialize):
     has_ev = np.array([bool(sl.events) for _, sl, _ in members])
     dev_job = np.repeat(np.arange(J), n_dev).astype(np.int32)
     strag = np.concatenate(strag_list).astype(np.float32)
+    # rows pad to a multiple of the mesh (copies of job 0, which the
+    # caller slices off) so every chip holds an equal share
+    pad = -len(strag) % mesh.size if mesh is not None else 0
+    dev_job = np.concatenate([dev_job, np.zeros(pad, np.int32)])
+    strag = np.concatenate([strag, np.ones(pad, np.float32)])
     t_end = (np.arange(S_max) + 1.0) * interval
 
     ratio = (mxu / step).astype(np.float32)
@@ -167,20 +198,9 @@ def _simulate_group_jax(members, out, rng, params, mesh, materialize):
               float(cm.chip.f_max_mhz), cm.throttle_frac)
     k_jit, k_clk = (jax.random.PRNGKey(int(rng.integers(0, 2 ** 31)))
                     for _ in range(2))
-    tpa, clock = _group_device_sim(
-        jnp.asarray(ratio), jnp.asarray(strag), jnp.asarray(dev_job),
-        jnp.asarray(sig), jnp.asarray(ev_base),
-        jnp.asarray(ev_rows), jnp.asarray(ev_job_of_row),
-        jnp.asarray(strag[ev_rows]), jnp.asarray(base_end),
-        k_jit, k_clk, S=S_max, n_sub=n_sub, consts=consts, mesh=mesh)
-
-    row0 = 0
-    for (i, _, _), nd, Sj in zip(members, n_dev, S):
-        t, c = tpa[row0:row0 + nd, :Sj], clock[row0:row0 + nd, :Sj]
-        if materialize:
-            t, c = np.asarray(t), np.asarray(c)
-        out[i] = DeviceGrid(interval, t, c)
-        row0 += nd
+    args = (ratio, strag, dev_job, sig, ev_base, ev_rows, ev_job_of_row,
+            strag[ev_rows], base_end, k_jit, k_clk)
+    return args, dict(S=S_max, n_sub=n_sub, consts=consts, mesh=mesh)
 
 
 def simulate_jobs_jax(slots: Sequence[JobSlot], *, seed: int = 0,

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import SHAPES, ShapeSpec, cache_specs, get_config
+from repro.launch.cache import enable_compile_cache
 from repro.models import api as models
 from repro.train.steps import make_serve_step
 
@@ -30,6 +31,7 @@ def main():
     ap.add_argument("--batch", type=int, default=2)
     ap.add_argument("--ctx-len", type=int, default=64)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

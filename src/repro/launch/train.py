@@ -13,7 +13,9 @@ import argparse
 import json
 
 from repro.configs.base import SHAPES, ShapeSpec, get_config
+from repro.core.peaks import TPU_V5E
 from repro.flops.accounting import step_flops
+from repro.launch.cache import enable_compile_cache
 from repro.optim import adamw
 from repro.train.trainer import TrainConfig, Trainer
 
@@ -30,6 +32,7 @@ def main():
     ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
     ap.add_argument("--ckpt-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:
@@ -44,7 +47,10 @@ def main():
         opt_cfg=adamw.OptConfig(warmup_steps=5, decay_steps=args.steps),
         train_cfg=TrainConfig(total_steps=args.steps,
                               ckpt_every=args.ckpt_every,
-                              ckpt_dir=args.ckpt_dir),
+                              ckpt_dir=args.ckpt_dir,
+                              # --smoke models a v5e's counters anywhere;
+                              # the full config reads the device's kind
+                              chip=TPU_V5E if args.smoke else None),
         flops_per_step=fl)
     out = trainer.run()
     print(json.dumps(out, indent=1, default=float))

@@ -103,8 +103,12 @@ class LibtpuTransport(TpuTransport):
                 "libtpu is present but exposes no duty-cycle metric "
                 "this shim recognizes (expected metrics.get_chip_usage)")
         usage = metrics.get_chip_usage()[device]
-        return (float(usage.duty_cycle_pct) / 100.0,
-                float(getattr(usage, "clock_mhz", 0.0)),
+        clock_mhz = getattr(usage, "clock_mhz", None)
+        if clock_mhz is None:
+            raise TransportError(
+                "libtpu chip usage carries no clock_mhz; OFU needs the "
+                "clock, and a missing one must not read as 0 MHz")
+        return (float(usage.duty_cycle_pct) / 100.0, float(clock_mhz),
                 float(self._clock()))
 
 

@@ -19,7 +19,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig, ShapeSpec
 from repro.core.ofu import ofu_point
-from repro.core.peaks import DEFAULT_CHIP, ChipSpec
+from repro.core.peaks import ChipSpec, device_chip
 from repro.data.pipeline import synthetic_batch
 from repro.fleet.recovery import RecoveryService, StragglerMonitor
 from repro.models import api as models
@@ -36,7 +36,9 @@ class TrainConfig:
     keep: int = 3
     seed: int = 0
     log_every: int = 10
-    chip: ChipSpec = DEFAULT_CHIP
+    #: the chip whose counters the monitor models; None = the device this
+    #: runs on, looked up by its device_kind (an unknown kind raises)
+    chip: Optional[ChipSpec] = None
     # OFU monitoring
     monitor: bool = True
     scrape_every_steps: int = 5
@@ -70,6 +72,7 @@ class Trainer:
         self.opt_cfg = opt_cfg or adamw.OptConfig(warmup_steps=10,
                                                   decay_steps=1000)
         self.tc = train_cfg or TrainConfig()
+        self.chip = self.tc.chip or device_chip()
         self.ctx = ctx
         self.fault_hook = fault_hook
         self.flops_per_step = flops_per_step
@@ -96,17 +99,16 @@ class Trainer:
         return params, opt_state
 
     def _telemetry(self, step: int, dt: float) -> StepTelemetry:
-        """Derive counter readings from the measured step time.
-
-        On TPU this is a scrape of the hardware counters; on CPU we compute
-        the duty cycle the chip WOULD show: mxu_time = flops/peak.
+        """Model counter readings from the measured step time: the duty
+        cycle the chip would show, mxu_time = flops / peak.  No hardware
+        counter is read here yet, on any backend.
         """
         if self.flops_per_step:
-            mxu_t = self.flops_per_step / (self.tc.chip.peak_tflops() * 1e12)
+            mxu_t = self.flops_per_step / (self.chip.peak_tflops() * 1e12)
         else:
             mxu_t = 0.35 * dt
         tpa = min(1.0, mxu_t / max(dt, 1e-9))
-        clock = self.tc.chip.f_max_mhz * (1 - 0.115 * tpa)
+        clock = self.chip.f_max_mhz * (1 - 0.115 * tpa)
         return StepTelemetry(step, dt, tpa, clock)
 
     # ------------------------------------------------------------------

@@ -349,6 +349,18 @@ def test_tpu_default_transport_is_gated_libtpu():
         be.poll(30.0)
 
 
+def test_libtpu_transport_missing_clock_raises():
+    """A chip-usage record without a clock fails loudly instead of
+    reading as 0 MHz (which would make OFU zero)."""
+    from types import SimpleNamespace as NS
+    t = LibtpuTransport(clock=lambda: 7.0)
+    usage = [NS(duty_cycle_pct=50.0), NS(duty_cycle_pct=40.0, clock_mhz=1400)]
+    t._lib = NS(metrics=NS(get_chip_usage=lambda: usage))
+    with pytest.raises(TransportError, match="clock_mhz"):
+        t.read(0)
+    assert t.read(1) == (0.4, 1400.0, 7.0)
+
+
 def test_lazy_reexport_from_counters():
     """`telemetry.counters.TpuProfilerBackend` stays importable (PEP 562
     forward) so pre-backends callers keep working."""

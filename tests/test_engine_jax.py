@@ -14,8 +14,8 @@ from repro.fleet import JobSpec, simulate_fleet, simulate_job  # noqa: E402
 from repro.fleet.engine import JobSlot, simulate_jobs_fused  # noqa: E402
 from repro.fleet.engine_jax import default_mesh, simulate_jobs_jax  # noqa: E402
 from repro.fleet.streaming import StreamingRollup, WindowedRollup  # noqa: E402
-from repro.kernels.fleet_hist import (_aligned_spb, bucket_hist_ref,  # noqa: E402
-                                      ofu_bucket_hist)
+from repro.kernels.fleet_hist import (_aligned_spb, _block_rows,  # noqa: E402
+                                      bucket_hist_ref, ofu_bucket_hist)
 from repro.telemetry import Event, StepProfile  # noqa: E402
 from repro.telemetry.scrape import DeviceGrid  # noqa: E402
 
@@ -203,15 +203,20 @@ def test_observe_hist_validates_bin_count():
 # ---------------------------------------------------------------------------
 # the fused histogram kernel itself (pallas + XLA vs the NumPy oracle)
 # ---------------------------------------------------------------------------
-def test_hist_kernel_pallas_and_xla_match_reference_exactly():
+@pytest.mark.parametrize("D,S,spb", [
+    (513, 40, 10),          # ragged last row tile (512-row tiles)
+    (1100, 25, 10),         # three tiles; short last bucket
+    (40, 2880, 7),          # a day of columns: 16-row tiles
+    (4, 10, 10),            # fewer rows than a sublane tile
+])
+def test_hist_kernel_pallas_and_xla_match_reference_exactly(D, S, spb):
     rng = np.random.default_rng(0)
-    D, S = 513, 40                      # deliberately unaligned row count
     tpa = rng.uniform(0, 1, (D, S)).astype(np.float32)
     clk = rng.uniform(900, 1558, (D, S)).astype(np.float32)
     edges = np.linspace(0.0, 1.1, 129)
-    col = np.arange(S) // 10
+    col = np.arange(S) // spb
     kw = dict(inv_fmax=1 / 1558.0, edges=edges, col_bucket=col,
-              n_buckets=4)
+              n_buckets=int(col[-1]) + 1)
     hr, sr = bucket_hist_ref(tpa, clk, **kw)
     assert hr.sum() == D * S            # every sample lands exactly once
     for use_pallas in (True, False):
@@ -243,6 +248,13 @@ def test_hist_kernel_rejects_bad_edges():
         ofu_bucket_hist(tpa, tpa, inv_fmax=1.0,
                         edges=np.array([0.0, 1.0, 0.5]),
                         col_bucket=np.zeros(2, int), n_buckets=1)
+
+
+def test_kernel_tiles_are_sublane_aligned_or_whole():
+    assert _block_rows(1_000_000, 120) == 512       # 64K samples a tile
+    assert _block_rows(100_000, 2880) == 16
+    assert _block_rows(4, 10) == 4                  # the whole array
+    assert _block_rows(10**6, 10**6) == 8
 
 
 def test_aligned_spb_detection():

@@ -15,6 +15,15 @@ def test_peak_derivation_matches_published():
     assert TPU_V5E.peak_tflops("fp32") == pytest.approx(196.608 / 4)
 
 
+def test_peaks_looked_up_by_device_kind():
+    from repro.core.peaks import chip_for_kind, device_chip
+    assert chip_for_kind("TPU v5 lite") is TPU_V5E
+    with pytest.raises(ValueError, match="no peak table entry"):
+        chip_for_kind("TPU v9 imaginary")
+    with pytest.raises(ValueError, match="'cpu'"):
+        device_chip(type("Dev", (), {"device_kind": "cpu"})())
+
+
 def test_ofu_point_eq1():
     # full duty at full clock = 1.0; clock throttle scales linearly
     assert ofu_point(1.0, TPU_V5E.f_max_mhz) == pytest.approx(1.0)

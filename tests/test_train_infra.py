@@ -9,6 +9,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.configs.base import ShapeSpec
+from repro.core.peaks import TPU_V5E
 from repro.data import synthetic_batch
 from repro.optim import adamw
 from repro.train import checkpoint as ckpt
@@ -117,7 +118,7 @@ def _mk_trainer(tmp_path, total=12, fault_hook=None):
         opt_cfg=adamw.OptConfig(warmup_steps=2, decay_steps=50),
         train_cfg=TrainConfig(total_steps=total, ckpt_every=4,
                               ckpt_dir=str(tmp_path / "ck"), log_every=2,
-                              monitor=False),
+                              monitor=False, chip=TPU_V5E),
         fault_hook=fault_hook)
 
 
