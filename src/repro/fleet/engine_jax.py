@@ -35,6 +35,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.core import spans
 from repro.fleet.engine import EngineParams, JobSlot, group_slots
 from repro.telemetry.counters import check_scrape_interval, event_factors
 from repro.telemetry.scrape import DeviceGrid
@@ -73,37 +74,41 @@ def _group_device_sim(ratio, strag, dev_job, sig, ev_base, ev_rows,
 
     # --- duty -> tpa: constant rows for event-free jobs, lax.scan mean
     # over the window sub-samples for evented rows ------------------------
-    duty_p = jnp.minimum(1.0, jnp.take(ratio, dev_job) / strag)
-    tpa_det = jnp.broadcast_to(duty_p[:, None], (D, S))
-    if ev_rows.shape[0]:
-        def sub_step(acc, base_k):               # base_k: (J_e, S)
-            d = jnp.minimum(1.0, jnp.take(base_k, ev_job_of_row, axis=0)
-                            / strag_e[:, None])
-            return acc + d, None
-        acc, _ = jax.lax.scan(
-            sub_step, jnp.zeros((ev_rows.shape[0], S), f32), ev_base)
-        tpa_det = tpa_det.at[ev_rows].set(acc * (1.0 / n_sub))
-    tpa_det = _shard(tpa_det, mesh)
+    with jax.named_scope("duty"):
+        duty_p = jnp.minimum(1.0, jnp.take(ratio, dev_job) / strag)
+        tpa_det = jnp.broadcast_to(duty_p[:, None], (D, S))
+        if ev_rows.shape[0]:
+            def sub_step(acc, base_k):               # base_k: (J_e, S)
+                d = jnp.minimum(1.0, jnp.take(base_k, ev_job_of_row, axis=0)
+                                / strag_e[:, None])
+                return acc + d, None
+            acc, _ = jax.lax.scan(
+                sub_step, jnp.zeros((ev_rows.shape[0], S), f32), ev_base)
+            tpa_det = tpa_det.at[ev_rows].set(acc * (1.0 / n_sub))
+        tpa_det = _shard(tpa_det, mesh)
     # single lognormal jitter draw, σ ≈ jitter / n_eff (NumPy path's
     # mean-of-n-jittered-subsamples dispersion)
-    z = jax.random.normal(k_jit, (D, S), dtype=f32)
-    tpa = jnp.clip(tpa_det * jnp.exp(z * sig[:, None]), 0.0, 1.0)
+    with jax.named_scope("jitter"):
+        z = jax.random.normal(k_jit, (D, S), dtype=f32)
+        tpa = jnp.clip(tpa_det * jnp.exp(z * sig[:, None]), 0.0, 1.0)
 
     # --- clock: exact OU discretization, one lax.scan step per sample ----
-    a, sd, f_min, f_max, throttle = consts
-    duty_end = jnp.minimum(1.0, jnp.take(base_end, dev_job, axis=0)
-                           / strag[:, None])
-    # drive = μ(duty)·(1−a) + σ·dW, time-major like simulate_batch
-    drive = (f_max * (1.0 - a)) * (1.0 - throttle * duty_end.T) \
-        + sd * jax.random.normal(k_clk, (S, D), dtype=f32)
+    with jax.named_scope("clock_ou"):
+        a, sd, f_min, f_max, throttle = consts
+        duty_end = jnp.minimum(1.0, jnp.take(base_end, dev_job, axis=0)
+                               / strag[:, None])
+        # drive = μ(duty)·(1−a) + σ·dW, time-major like simulate_batch
+        drive = (f_max * (1.0 - a)) * (1.0 - throttle * duty_end.T) \
+            + sd * jax.random.normal(k_clk, (S, D), dtype=f32)
 
-    def ou_step(cur, dr):
-        cur = jnp.clip(cur * a + dr, f_min, f_max)
-        return cur, cur
+        def ou_step(cur, dr):
+            cur = jnp.clip(cur * a + dr, f_min, f_max)
+            return cur, cur
 
-    cur0 = f_max * (1.0 - throttle * duty_end[:, 0])   # mean_clock(duty₀)
-    _, f = jax.lax.scan(ou_step, cur0, drive)
-    return tpa, _shard(f.T, mesh)
+        cur0 = f_max * (1.0 - throttle * duty_end[:, 0])  # mean_clock(duty₀)
+        _, f = jax.lax.scan(ou_step, cur0, drive)
+        f = _shard(f.T, mesh)
+    return tpa, f
 
 
 def _group_dims(members):
@@ -126,17 +131,20 @@ def _simulate_group_jax(members, out, rng, params, mesh, materialize):
             out[i] = DeviceGrid(interval, np.empty((len(st), 0)),
                                 np.empty((len(st), 0)))
         return
-    args, static = _group_inputs(members, rng, params, mesh)
-    tpa, clock = _group_device_sim(*(jnp.asarray(a) for a in args),
-                                   **static)
+    with spans.span("engine.inputs"):
+        args, static = _group_inputs(members, rng, params, mesh)
+        args = [jnp.asarray(a) for a in args]
+    tpa, clock = _group_device_sim(*args, **static)
+    del args                # free the inputs' device copies with the call
     row0 = 0
-    for (i, _, _), st, Sj in zip(members, strag_list, S):
-        nd = len(st)
-        t, c = tpa[row0:row0 + nd, :Sj], clock[row0:row0 + nd, :Sj]
-        if materialize:
-            t, c = np.asarray(t), np.asarray(c)
-        out[i] = DeviceGrid(interval, t, c)
-        row0 += nd
+    with spans.span("engine.slice"):
+        for (i, _, _), st, Sj in zip(members, strag_list, S):
+            nd = len(st)
+            t, c = tpa[row0:row0 + nd, :Sj], clock[row0:row0 + nd, :Sj]
+            if materialize:
+                t, c = np.asarray(t), np.asarray(c)
+            out[i] = DeviceGrid(interval, t, c)
+            row0 += nd
 
 
 def _group_inputs(members, rng, params, mesh):

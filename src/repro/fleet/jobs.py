@@ -16,6 +16,7 @@ from typing import Optional, Sequence
 import numpy as np
 
 from repro.configs.base import SHAPES, ShapeSpec, get_config
+from repro.core import spans
 from repro.core.ofu import effective_peak, ofu_mean
 from repro.core.peaks import DEFAULT_CHIP, ChipSpec
 from repro.core.tile_quant import pick_policy, profiled_flops, theoretical_flops
@@ -165,6 +166,8 @@ _DRAW_CACHE: dict = {}
 def _job_draws(seed: int, sigma: float, n_dev: int):
     key = (seed, sigma, n_dev)
     hit = _DRAW_CACHE.get(key)
+    spans.count("fleet.draw_cache.miss" if hit is None
+                else "fleet.draw_cache.hit")
     if hit is None:
         rng = np.random.default_rng(seed)
         stragglers = np.exp(rng.standard_normal(n_dev) * sigma)
@@ -251,13 +254,15 @@ def _simulate_fleet_fused(specs: Sequence[JobSpec], max_devices: int, *,
     from repro.fleet.engine import JobSlot, simulate_jobs_fused
 
     slots, meta, entropy = [], [], []
-    for spec in specs:
-        prof, app, app_exact, stragglers, seeds = _prep_job(spec, max_devices)
-        slots.append(JobSlot(prof, spec.duration_s, spec.scrape_interval_s,
-                             events=spec.events, stragglers=stragglers,
-                             chip=spec.chip))
-        meta.append((spec, prof, app, app_exact))
-        entropy.append(int(seeds[0]))
+    with spans.span("fleet.prep"):
+        for spec in specs:
+            prof, app, app_exact, stragglers, seeds = _prep_job(
+                spec, max_devices)
+            slots.append(JobSlot(prof, spec.duration_s,
+                                 spec.scrape_interval_s, events=spec.events,
+                                 stragglers=stragglers, chip=spec.chip))
+            meta.append((spec, prof, app, app_exact))
+            entropy.append(int(seeds[0]))
     # one master seed for the fused grid's shared RNG streams, derived
     # deterministically from every job's own stream
     seed = int(np.random.default_rng(entropy or [0]).integers(0, 2 ** 31))

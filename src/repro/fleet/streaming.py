@@ -22,6 +22,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from repro.core import spans
 from repro.core.ofu import hist_percentile, hist_percentile_grid, ofu_series
 from repro.core.peaks import DEFAULT_CHIP, ChipSpec
 from repro.fleet import wire
@@ -240,10 +241,14 @@ class StreamingRollup:
         hist, sums = ofu_bucket_hist(
             grid.tpa, grid.clock_mhz, inv_fmax=inv_fmax, edges=self.edges,
             col_bucket=b_abs - b0, n_buckets=int(b_abs[-1]) - b0 + 1)
-        self.observe_hist(job_id, np.asarray(hist, float),
-                          np.asarray(sums, float), b0=b0, group=group,
-                          weight=weight)
-        return grid.tpa * grid.clock_mhz * inv_fmax
+        with spans.span("rollup.fetch"):
+            hist = np.asarray(hist, float)
+            sums = np.asarray(sums, float)
+        with spans.span("rollup.observe"):
+            self.observe_hist(job_id, hist, sums, b0=b0, group=group,
+                              weight=weight)
+        with spans.span("rollup.ofu"):
+            return grid.tpa * grid.clock_mhz * inv_fmax
 
     def observe_hist(self, job_id: str, hist: np.ndarray,
                      sums: np.ndarray, *, b0: int = 0,

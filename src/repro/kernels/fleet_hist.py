@@ -54,6 +54,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
+from repro.core import spans
 from repro.kernels.ops import _interpret
 
 
@@ -150,6 +151,7 @@ def _hist_tiles(edges, tpa, clock, *, inv_fmax, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="ofu_hist",
     )(edges, tpa, clock)
 
 
@@ -239,26 +241,28 @@ def ofu_bucket_hist(tpa, clock, *, inv_fmax: float, edges: np.ndarray,
     always takes the XLA scatter.  A grid whose rows are sharded over a
     mesh runs the kernel on each chip's rows.
     """
-    edges = _edges_f32(edges)
-    col_bucket = np.asarray(col_bucket, np.int32)
-    spb = _aligned_spb(col_bucket, n_buckets)
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    tpa, clock = jnp.asarray(tpa), jnp.asarray(clock)
-    if use_pallas and spb is not None:
-        interpret = _interpret()
-        ROUTES["pallas-interpret" if interpret else "pallas"] += 1
-        kw = dict(spb=spb, n_buckets=n_buckets, inv_fmax=float(inv_fmax),
-                  interpret=interpret)
-        sharded = _row_axis(tpa)
-        if sharded is not None:
-            mesh, axis = sharded
-            return _hist_pallas_sharded(tpa, clock, jnp.asarray(edges),
-                                        mesh=mesh, axis=axis, **kw)
-        return _hist_pallas(tpa, clock, jnp.asarray(edges), **kw)
-    ROUTES["xla"] += 1
-    return _hist_xla(tpa, clock, jnp.asarray(edges), jnp.asarray(col_bucket),
-                     n_buckets=n_buckets, inv_fmax=float(inv_fmax))
+    with spans.span("hist.launch"):
+        edges = _edges_f32(edges)
+        col_bucket = np.asarray(col_bucket, np.int32)
+        spb = _aligned_spb(col_bucket, n_buckets)
+        if use_pallas is None:
+            use_pallas = jax.default_backend() == "tpu"
+        tpa, clock = jnp.asarray(tpa), jnp.asarray(clock)
+        if use_pallas and spb is not None:
+            interpret = _interpret()
+            ROUTES["pallas-interpret" if interpret else "pallas"] += 1
+            kw = dict(spb=spb, n_buckets=n_buckets,
+                      inv_fmax=float(inv_fmax), interpret=interpret)
+            sharded = _row_axis(tpa)
+            if sharded is not None:
+                mesh, axis = sharded
+                return _hist_pallas_sharded(tpa, clock, jnp.asarray(edges),
+                                            mesh=mesh, axis=axis, **kw)
+            return _hist_pallas(tpa, clock, jnp.asarray(edges), **kw)
+        ROUTES["xla"] += 1
+        return _hist_xla(tpa, clock, jnp.asarray(edges),
+                         jnp.asarray(col_bucket), n_buckets=n_buckets,
+                         inv_fmax=float(inv_fmax))
 
 
 def bucket_hist_ref(tpa, clock, *, inv_fmax: float, edges: np.ndarray,

@@ -86,20 +86,22 @@ def block_apply(cfg: ModelConfig, p, x, positions, ctx, *, moe: bool,
                 causal: bool = True):
     # norm outputs pinned to SP: the attention/MLP full-sequence gather
     # then moves to the bf16 tensor instead of the f32 rms upcast
-    h = _sp(rms_norm(x, p["norm1"], cfg.norm_eps), ctx)
-    if cfg.family == "mla_moe":
-        a = attn.mla_apply(cfg, p["attn"], h, positions=positions,
-                           causal=causal, ctx=ctx)
-    else:
-        a = attn.gqa_apply(cfg, p["attn"], h, positions=positions,
-                           causal=causal, ctx=ctx)
-    x = _sp(x + a, ctx)
-    h = _sp(rms_norm(x, p["norm2"], cfg.norm_eps), ctx)
-    if moe:
-        m = moe_mod.moe_apply(cfg, p["mlp"], h, ctx)
-    else:
-        m = moe_mod.mlp_apply(cfg, p["mlp"], h, ctx)
-    return _sp(x + m, ctx)
+    with jax.named_scope("attention"):
+        h = _sp(rms_norm(x, p["norm1"], cfg.norm_eps), ctx)
+        if cfg.family == "mla_moe":
+            a = attn.mla_apply(cfg, p["attn"], h, positions=positions,
+                               causal=causal, ctx=ctx)
+        else:
+            a = attn.gqa_apply(cfg, p["attn"], h, positions=positions,
+                               causal=causal, ctx=ctx)
+        x = _sp(x + a, ctx)
+    with jax.named_scope("mlp"):
+        h = _sp(rms_norm(x, p["norm2"], cfg.norm_eps), ctx)
+        if moe:
+            m = moe_mod.moe_apply(cfg, p["mlp"], h, ctx)
+        else:
+            m = moe_mod.mlp_apply(cfg, p["mlp"], h, ctx)
+        return _sp(x + m, ctx)
 
 
 def _remat(fn, cfg: ModelConfig):
@@ -136,7 +138,8 @@ def embed_inputs(cfg: ModelConfig, params, batch, ctx):
 def forward(cfg: ModelConfig, params, batch, ctx: Optional[ShardCtx] = None,
             return_hidden: bool = False):
     """Full-sequence forward -> logits (B, S, V)."""
-    x = embed_inputs(cfg, params, batch, ctx)
+    with jax.named_scope("embed"):
+        x = embed_inputs(cfg, params, batch, ctx)
     S = x.shape[1]
     positions = jnp.arange(S)
     n_dense = cfg.first_dense_layers if cfg.num_experts else cfg.num_layers
@@ -145,8 +148,9 @@ def forward(cfg: ModelConfig, params, batch, ctx: Optional[ShardCtx] = None,
                        moe=False)
     if "moe_layers" in params:
         x = scan_stack(cfg, params["moe_layers"], x, positions, ctx, moe=True)
-    h = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = lm_logits(cfg, params, h, ctx)
+    with jax.named_scope("logits"):
+        h = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = lm_logits(cfg, params, h, ctx)
     if return_hidden:
         return logits, h
     return logits

@@ -19,12 +19,13 @@ from repro.optim import adamw
 
 def cross_entropy(logits: jax.Array, labels: jax.Array) -> jax.Array:
     """Mean next-token CE; vocab axis may be sharded (einsum-reduced)."""
-    V = logits.shape[-1]
-    lf = logits.astype(jnp.float32)
-    lse = jax.nn.logsumexp(lf, axis=-1)
-    onehot = jax.nn.one_hot(labels, V, dtype=jnp.float32)
-    ll = jnp.einsum("...v,...v->...", lf, onehot)
-    return jnp.mean(lse - ll)
+    with jax.named_scope("loss"):
+        V = logits.shape[-1]
+        lf = logits.astype(jnp.float32)
+        lse = jax.nn.logsumexp(lf, axis=-1)
+        onehot = jax.nn.one_hot(labels, V, dtype=jnp.float32)
+        ll = jnp.einsum("...v,...v->...", lf, onehot)
+        return jnp.mean(lse - ll)
 
 
 def loss_fn(cfg: ModelConfig, params, batch,
@@ -56,8 +57,13 @@ def make_train_step(cfg: ModelConfig, opt_cfg: adamw.OptConfig,
     """
 
     def grads_of(params, batch):
-        return jax.value_and_grad(
-            lambda p: loss_fn(cfg, p, batch, ctx), has_aux=True)(params)
+        # value_and_grad spelled as vjp, so the transposed half of the
+        # step carries its own name scope
+        loss, pullback, aux = jax.vjp(
+            lambda p: loss_fn(cfg, p, batch, ctx), params, has_aux=True)
+        with jax.named_scope("backward"):
+            (grads,) = pullback(jnp.ones_like(loss))
+        return (loss, aux), grads
 
     def train_step(params, opt_state, batch):
         if accum_steps == 1:
@@ -81,8 +87,9 @@ def make_train_step(cfg: ModelConfig, opt_cfg: adamw.OptConfig,
                 lambda p: jnp.zeros(p.shape, jnp.float32), params)
             grads, auxs = jax.lax.scan(body, zeros, micro)
             aux = jax.tree.map(lambda x: x.mean(), auxs)
-        params, opt_state, om = adamw.update(opt_cfg, grads, opt_state,
-                                             params)
+        with jax.named_scope("adamw"):
+            params, opt_state, om = adamw.update(opt_cfg, grads, opt_state,
+                                                 params)
         aux.update(om)
         return params, opt_state, aux
 

@@ -1,6 +1,8 @@
 """Per-architecture smoke tests: REDUCED same-family config, one forward +
 one train step + one decode step on CPU; asserts shapes + no NaNs.
 (The FULL configs are exercised only via the dry-run.)"""
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -94,3 +96,19 @@ def test_decode_matches_forward_incrementally():
     for t in range(T):
         np.testing.assert_allclose(dec_logits[t], full[:, t], rtol=2e-2,
                                    atol=2e-2)
+
+
+@pytest.mark.parametrize("arch", ["qwen3-4b", "deepseek-moe-16b"])
+def test_train_step_stages_are_named(arch):
+    """Each stage of the step names its ops, so a device trace can split
+    the step by stage; the backward pass carries its own scope."""
+    cfg, params = _state(arch)
+    opt_cfg = adamw.OptConfig()
+    batch = make_inputs(cfg, ShapeSpec("t", 32, 2, "train"))
+    text = jax.jit(make_train_step(cfg, opt_cfg)).lower(
+        params, adamw.init(opt_cfg, params), batch).as_text(debug_info=True)
+    names = re.findall(r'loc\("([^"]*)"', text)
+    for stage in ("embed", "attention", "mlp", "logits", "loss", "adamw"):
+        assert any(re.search(rf"[/(]{stage}[/)]", n) for n in names), stage
+    backward = [n for n in names if "backward/" in n]
+    assert backward and all("transpose(" in n for n in backward)

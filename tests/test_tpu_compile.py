@@ -109,3 +109,23 @@ def test_hist_kernel_partitions_over_v5e_mesh(topo):
     assert f"f32[{D // 4},{S}]" in text
     assert compiled.memory_analysis().argument_size_in_bytes \
         < D * S * 4                             # a quarter of each grid
+
+
+def test_device_programs_carry_stable_stage_names(one_chip):
+    """The kernel's custom call is named `ofu_hist`, and the engine's
+    stages (`duty`, `jitter`, `clock_ou`) reach the compiled ops'
+    metadata, so a device trace can split each program by stage."""
+    S = 120
+    kernel = _compile_hist(one_chip, 1024, S, 10, 12).as_text()
+    assert "%ofu_hist" in kernel and "tpu_custom_call" in kernel
+    slot = JobSlot(StepProfile(mxu_time_s=0.84, step_time_s=2.0), 3600.0,
+                   30.0, events=[Event(600, 1200, slowdown=2.5)],
+                   stragglers=np.ones(1024))
+    (members,) = group_slots([slot]).values()
+    args, static = _group_inputs(members, np.random.default_rng(0),
+                                 EngineParams(), None)
+    shapes = [_sds(np.shape(a), jnp.asarray(a).dtype, one_chip)
+              for a in args]
+    engine = _group_device_sim.lower(*shapes, **static).compile().as_text()
+    for stage in ("duty", "jitter", "clock_ou"):
+        assert f"jit(_group_device_sim)/{stage}/" in engine, stage
