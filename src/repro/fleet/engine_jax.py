@@ -16,7 +16,11 @@ accelerators).  Same structure, device arrays instead of ndarrays:
     a `lax.scan` over time with a (D,) carry;
   * grids carry a `with_sharding_constraint` over a 1-D device mesh
     (rows = devices axis), so on multi-chip hosts XLA partitions the
-    whole pipeline; on a single device it is a no-op.
+    whole pipeline; on a single device it is a no-op;
+  * one more jitted program, `_split_group`, cuts the group's grids
+    into every member's (rows, S_j) grids in a single dispatch.  It is
+    specialised on the members' sizes only (row starts are traced), so
+    a fleet that keeps its job sizes compiles it once.
 
 Equivalence to the NumPy reference is statistical, not bitwise (jax
 threefry vs NumPy philox draws), frozen by the same-tolerance property
@@ -123,8 +127,8 @@ def _group_dims(members):
 
 
 def _simulate_group_jax(members, out, rng, params, mesh, materialize):
-    """One fused group: host prep, one jitted device call, then a
-    DeviceGrid per member sliced from the group's rows."""
+    """One fused group: host prep, one jitted device call, then one
+    jitted split of its rows into a DeviceGrid per member."""
     interval, strag_list, S = _group_dims(members)
     if int(S.max()) <= 0:
         for (i, _, _), st in zip(members, strag_list):
@@ -135,16 +139,38 @@ def _simulate_group_jax(members, out, rng, params, mesh, materialize):
         args, static = _group_inputs(members, rng, params, mesh)
         args = [jnp.asarray(a) for a in args]
     tpa, clock = _group_device_sim(*args, **static)
-    del args                # free the inputs' device copies with the call
-    row0 = 0
+    # free the inputs' device copies with the call; they live until it
+    # ends, so they overlap the split's outputs (12 bytes a device row)
+    del args
     with spans.span("engine.slice"):
-        for (i, _, _), st, Sj in zip(members, strag_list, S):
-            nd = len(st)
-            t, c = tpa[row0:row0 + nd, :Sj], clock[row0:row0 + nd, :Sj]
-            if materialize:
-                t, c = np.asarray(t), np.asarray(c)
-            out[i] = DeviceGrid(interval, t, c)
-            row0 += nd
+        # sizes in a canonical order, so a reordered layout of the same
+        # sizes reuses the compile; the padded mesh rows are left out
+        n_dev = np.array([len(st) for st in strag_list])
+        order = np.lexsort((S, n_dev))
+        starts = (np.cumsum(n_dev) - n_dev)[order].astype(np.int32)
+        sizes = tuple((int(n_dev[k]), int(S[k])) for k in order)
+        cached = _split_group._cache_size()
+        parts = _split_group(tpa, clock, starts, sizes=sizes)
+        spans.count("engine.split.calls")
+        spans.count("engine.split.jobs", len(sizes))
+        spans.count("engine.split.compiles",
+                    _split_group._cache_size() - cached)
+        if materialize:
+            parts = jax.device_get(parts)
+        for m, k in enumerate(order):
+            out[members[k][0]] = DeviceGrid(interval, parts[2 * m],
+                                            parts[2 * m + 1])
+
+
+@functools.partial(jax.jit, static_argnames=("sizes",))
+def _split_group(tpa, clock, starts, *, sizes: tuple):
+    """Every member's rows and columns of a group's (tpa, clock), in one
+    program: `(tpa_0, clock_0, tpa_1, ...)`, block m of shape `sizes[m]`
+    = (rows, S_j) starting at row `starts[m]` and column 0.  Only the
+    sizes are static, so one compile serves any placement of them."""
+    return tuple(jax.lax.dynamic_slice(x, (starts[m], 0), shape,
+                                       allow_negative_indices=False)
+                 for m, shape in enumerate(sizes) for x in (tpa, clock))
 
 
 def _group_inputs(members, rng, params, mesh):

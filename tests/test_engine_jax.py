@@ -2,6 +2,10 @@
 against the NumPy fused reference at the fused-vs-scalar tolerances, the
 fused pallas/XLA histogram ingest producing rollups bucketwise IDENTICAL
 to the host path, and the `simulate_fleet(engine="jax")` dispatch."""
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
@@ -10,9 +14,13 @@ from _propcheck import given, settings, st
 jax = pytest.importorskip("jax")
 jnp = jax.numpy
 
+from repro.core import spans  # noqa: E402
 from repro.fleet import JobSpec, simulate_fleet, simulate_job  # noqa: E402
-from repro.fleet.engine import JobSlot, simulate_jobs_fused  # noqa: E402
-from repro.fleet.engine_jax import default_mesh, simulate_jobs_jax  # noqa: E402
+from repro.fleet.engine import (EngineParams, JobSlot,  # noqa: E402
+                                group_slots, simulate_jobs_fused)
+from repro.fleet.engine_jax import (_group_device_sim,  # noqa: E402
+                                    _group_dims, _group_inputs,
+                                    default_mesh, simulate_jobs_jax)
 from repro.fleet.streaming import StreamingRollup, WindowedRollup  # noqa: E402
 from repro.kernels.fleet_hist import (_aligned_spb, _block_rows,  # noqa: E402
                                       bucket_hist_ref, ofu_bucket_hist)
@@ -107,6 +115,92 @@ def test_multi_job_grouping_and_ragged_slices_match_numpy_layout():
     assert grids[1].interval_s == 15.0
     assert np.asarray(grids[0].clock_mhz).max() <= 1500.0
     assert np.asarray(grids[2].clock_mhz).mean() > 1500.0
+
+
+# one group of ragged members (a zero-width one among them) and a second
+# group; 11 and 2 rows, so a 4-device mesh pads both
+RAGGED = [JobSlot(StepProfile(0.8, 2.0), 600, 30.0, stragglers=np.ones(3)),
+          JobSlot(StepProfile(0.5, 2.0), 10.0, 30.0),
+          JobSlot(StepProfile(0.6, 2.0), 450, 15.0, stragglers=np.ones(2)),
+          JobSlot(StepProfile(0.6, 2.0), 450, 30.0,
+                  stragglers=np.linspace(1.0, 1.4, 5)),
+          JobSlot(StepProfile(0.7, 2.0), 270, 30.0, stragglers=np.ones(2))]
+
+
+def _eager_slices(slots, seed, mesh):
+    """Each member's (tpa, clock) cut from its group program's output by
+    eager slices, with `simulate_jobs_jax`'s draws: the split's reference."""
+    rng = np.random.default_rng(seed)
+    out = [None] * len(slots)
+    for members in group_slots(slots).values():
+        _, strag_list, S = _group_dims(members)
+        args, static = _group_inputs(members, rng, EngineParams(), mesh)
+        tpa, clock = _group_device_sim(*map(jnp.asarray, args), **static)
+        row0 = 0
+        for (i, _, _), st, Sj in zip(members, strag_list, S):
+            nd = len(st)
+            out[i] = (tpa[row0:row0 + nd, :Sj], clock[row0:row0 + nd, :Sj])
+            row0 += nd
+    return out
+
+
+def _assert_split_is_eager_slices(materialize, devices):
+    assert len(jax.devices()) == devices
+    mesh = default_mesh()
+    grids = simulate_jobs_jax(RAGGED, seed=7, mesh=mesh,
+                              materialize=materialize)
+    for g, want in zip(grids, _eager_slices(RAGGED, 7, mesh)):
+        for got, ref in zip((g.tpa, g.clock_mhz), want):
+            assert isinstance(got, np.ndarray) == materialize
+            assert (got.shape, got.dtype) == (ref.shape, ref.dtype)
+            assert np.array_equal(np.asarray(got), np.asarray(ref))
+            # an empty eager slice lands on one device; it holds no data
+            if not materialize and ref.size:
+                assert got.sharding == ref.sharding
+
+
+@pytest.mark.parametrize("materialize", [False, True])
+@pytest.mark.parametrize("devices", [1, 4])
+def test_split_grids_are_the_eager_slices_bitwise(devices, materialize):
+    """The one split program gives each member exactly what slicing the
+    group's grids row by row gave: values, dtype, shape and sharding,
+    padded mesh rows left out; materialize=True as NumPy arrays."""
+    if devices == 1:
+        _assert_split_is_eager_slices(materialize, 1)
+        return
+    flags = os.environ.get("XLA_FLAGS", "")
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(sys.path),
+               JAX_PLATFORMS="cpu", XLA_FLAGS=f"{flags} --xla_force_host_"
+               f"platform_device_count={devices}".strip())
+    code = ("from test_engine_jax import _assert_split_is_eager_slices as f;"
+            f" f({materialize}, {devices})")
+    subprocess.run([sys.executable, "-c", code], check=True, env=env,
+                   cwd=os.path.dirname(__file__))
+
+
+def test_split_counters_one_call_a_group_one_compile_a_layout(tmp_path):
+    """Each group's split is one dispatch returning its J members; the
+    program compiles once for a layout of sizes, whatever their order."""
+    names = ("calls", "jobs", "compiles")
+
+    def round_(slots):
+        before = spans.snapshot()["counters"]
+        simulate_jobs_jax(slots, seed=1)
+        after = spans.snapshot()["counters"]
+        return tuple(after.get(f"engine.split.{n}", 0)
+                     - before.get(f"engine.split.{n}", 0) for n in names)
+
+    # sizes no other test uses, so the first round meets a new layout
+    def slot(n_dev, dur, interval=30.0):
+        return JobSlot(_profile(0.4), dur, interval,
+                       stragglers=np.ones(n_dev))
+    slots = [slot(6, 330), slot(7, 390), slot(9, 330), slot(5, 195, 15.0),
+             slot(6, 240, 15.0)]
+    with jax.profiler.trace(str(tmp_path)):
+        assert round_(slots) == (2, 5, 2)               # two groups
+        assert round_(slots) == (2, 5, 0)
+        assert round_(slots[::-1]) == (2, 5, 0)         # another order
+        assert round_(slots[:2] + [slot(9, 360)] + slots[3:]) == (2, 5, 1)
 
 
 @settings(max_examples=10, derandomize=True, deadline=None)

@@ -19,7 +19,8 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec,  # noqa: E402
                           SingleDeviceSharding)
 
 from repro.fleet.engine import EngineParams, JobSlot, group_slots  # noqa: E402
-from repro.fleet.engine_jax import _group_device_sim, _group_inputs  # noqa: E402
+from repro.fleet.engine_jax import (_group_device_sim,  # noqa: E402
+                                    _group_inputs, _split_group)
 from repro.kernels.fleet_hist import (_hist_pallas,  # noqa: E402
                                       _hist_pallas_sharded)
 from repro.telemetry import Event, StepProfile  # noqa: E402
@@ -89,6 +90,21 @@ def test_engine_group_step_compiles_for_v5e(one_chip):
     mem = compiled.memory_analysis()
     assert 2 * 1_000_000 * 120 * 4 <= mem.output_size_in_bytes < 1e9
     assert mem.temp_size_in_bytes < 8 * 2 ** 30
+
+
+def test_group_split_compiles_for_v5e(one_chip):
+    """The split of an 81-job x 12,288-device group into per-job grids:
+    one program, 162 outputs that together copy the group's grids once."""
+    J, nd, S = 81, 12_288, 120
+    grid = _sds((J * nd, S), jnp.float32, one_chip)
+    compiled = _split_group.lower(
+        grid, grid, _sds((J,), jnp.int32, one_chip),
+        sizes=((nd, S),) * J).compile()
+    mem = compiled.memory_analysis()
+    grids = 2 * J * nd * S * 4
+    assert grids <= mem.output_size_in_bytes < grids + 2 ** 16   # + tuple
+    assert mem.temp_size_in_bytes < nd * S * 4
+    assert compiled.as_text().count("dynamic-slice(") >= 2 * J
 
 
 def test_hist_kernel_partitions_over_v5e_mesh(topo):
