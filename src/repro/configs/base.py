@@ -62,11 +62,23 @@ class ModelConfig:
     num_shared_experts: int = 0
     top_k: int = 0
     d_ff_expert: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # the sharded (GShard) path only
     first_dense_layers: int = 0  # deepseek: leading dense MLP layers
+    # router: "softmax" or "sigmoid" scores over all experts; with
+    # router_bias a per-expert correction bias (not trained by the
+    # gradient) is added to the scores for choosing the top-k only
+    router_score: str = "softmax"
+    router_bias: bool = False
+    routed_scaling: float = 1.0     # the renormalised weights times this
+    balance_alpha: float = 0.0      # sequence-wise balance loss weight
+    bias_rate: float = 0.0          # bias step after each train step
+    # expert parallelism: this chip holds experts
+    # [ep_rank * E / ep_size, (ep_rank + 1) * E / ep_size) of num_experts
+    ep_size: int = 1
+    ep_rank: int = 0
 
     # --- MLA (deepseek-v3) ---
-    q_lora_rank: int = 0
+    q_lora_rank: int = 0  # 0: queries by one direct wq (no q-LoRA)
     kv_lora_rank: int = 0
     qk_rope_dim: int = 0
     qk_nope_dim: int = 0
@@ -114,6 +126,11 @@ class ModelConfig:
         return self.d_inner // self.ssm_head_dim
 
     @property
+    def experts_held(self) -> int:
+        """Experts of each MoE layer this chip holds."""
+        return self.num_experts // self.ep_size
+
+    @property
     def attention_free(self) -> bool:
         return self.family == "ssm"
 
@@ -139,11 +156,12 @@ class ModelConfig:
             vocab_size=256,
         )
         if self.num_experts:
-            kw.update(num_experts=4, top_k=2, d_ff_expert=32,
+            kw.update(num_experts=4 * self.ep_size, top_k=2, d_ff_expert=32,
                       num_shared_experts=min(self.num_shared_experts, 1),
                       first_dense_layers=min(self.first_dense_layers, 1))
         if self.q_lora_rank or self.kv_lora_rank:
-            kw.update(q_lora_rank=32, kv_lora_rank=16, qk_rope_dim=8,
+            kw.update(q_lora_rank=32 if self.q_lora_rank else 0,
+                      kv_lora_rank=16, qk_rope_dim=8,
                       qk_nope_dim=8, v_head_dim=16, head_dim=16)
         if self.mtp_depth:
             kw.update(mtp_depth=1)
@@ -186,7 +204,7 @@ def _load_all() -> None:
     from repro.configs import (  # noqa: F401
         deepseek_moe_16b, deepseek_v3_671b, qwen3_4b, nemotron_4_340b,
         granite_3_2b, llama3_2_3b, whisper_small, phi_3_vision_4_2b,
-        mamba2_780m, zamba2_7b,
+        mamba2_780m, zamba2_7b, moonlight_16b_a3b,
     )
 
 

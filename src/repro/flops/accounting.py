@@ -75,11 +75,18 @@ def _mla_flops(cfg: ModelConfig, ctx_len: float, causal: bool) -> dict:
     qr, kvr = cfg.q_lora_rank, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
     eff = ctx_len * (0.5 if causal else 1.0)
-    proj = (2 * d * qr + 2 * qr * H * (dn + dr)          # q path
+    proj = (_mla_q_flops(cfg)                            # q path
             + 2 * d * (kvr + dr) + 2 * kvr * H * (dn + dv)  # kv path
             + 2 * H * dv * d)                            # out
     score = 2 * eff * H * (dn + dr) + 2 * eff * H * dv
     return {"attn_proj": proj, "attn_score": score}
+
+
+def _mla_q_flops(cfg: ModelConfig) -> float:
+    """Query path: through the q-LoRA, or one direct wq without it."""
+    d, H, qr = cfg.d_model, cfg.num_heads, cfg.q_lora_rank
+    width = H * (cfg.qk_nope_dim + cfg.qk_rope_dim)
+    return 2 * d * qr + 2 * qr * width if qr else 2 * d * width
 
 
 def _mla_decode_flops(cfg: ModelConfig, ctx_len: float) -> dict:
@@ -87,7 +94,7 @@ def _mla_decode_flops(cfg: ModelConfig, ctx_len: float) -> dict:
     d, H = cfg.d_model, cfg.num_heads
     qr, kvr = cfg.q_lora_rank, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
-    proj = (2 * d * qr + 2 * qr * H * (dn + dr)
+    proj = (_mla_q_flops(cfg)
             + 2 * d * (kvr + dr)
             + 2 * H * dn * kvr          # absorb w_k into q
             + 2 * H * kvr * dv          # absorb w_v out of o_latent
@@ -117,7 +124,10 @@ def _moe_flops(cfg: ModelConfig, variant: str, executed: bool) -> dict:
             # capacity padding: slots are computed whether full or not
             C = capacity(cfg, 4096)
             pad = C * E / (4096 * cfg.top_k)
-        out["experts"] = cfg.top_k * _mlp_flops(cfg, cfg.d_ff_expert) * pad
+        # the held share: its expected top_k * G / E pairs a token
+        share = cfg.experts_held / E
+        out["experts"] = (cfg.top_k * _mlp_flops(cfg, cfg.d_ff_expert)
+                          * share * pad)
     if cfg.num_shared_experts:
         out["shared_experts"] = _mlp_flops(
             cfg, cfg.d_ff_expert * cfg.num_shared_experts)
@@ -328,9 +338,7 @@ def param_count_analytic(cfg: ModelConfig, active_only: bool = False) -> float:
 
     def attn_params():
         if cfg.family == "mla_moe":
-            return (d * cfg.q_lora_rank
-                    + cfg.q_lora_rank * cfg.num_heads
-                    * (cfg.qk_nope_dim + cfg.qk_rope_dim)
+            return (_mla_q_flops(cfg) / 2
                     + d * (cfg.kv_lora_rank + cfg.qk_rope_dim)
                     + cfg.kv_lora_rank * cfg.num_heads
                     * (cfg.qk_nope_dim + cfg.v_head_dim)
@@ -349,7 +357,8 @@ def param_count_analytic(cfg: ModelConfig, active_only: bool = False) -> float:
         n += L * attn_params()
         ff_dense = cfg.d_ff * (8 if cfg.family == "moe" else 1)
         n += nd * per_mlp * d * ff_dense
-        e = cfg.top_k if active_only else cfg.num_experts
+        e = (cfg.top_k * cfg.experts_held / cfg.num_experts if active_only
+             else cfg.experts_held)
         n += (L - nd) * (e + cfg.num_shared_experts) \
             * per_mlp * d * cfg.d_ff_expert
         n += (L - nd) * d * cfg.num_experts  # router

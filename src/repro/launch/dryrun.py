@@ -31,8 +31,8 @@ from repro.launch.sharding import (batch_shardings, opt_state_shardings,
                                    param_shardings)
 from repro.models import api as models
 from repro.optim import adamw
-from repro.train.steps import make_prefill_step, make_serve_step, \
-    make_train_step
+from repro.train.steps import init_opt_state, make_prefill_step, \
+    make_serve_step, make_train_step
 
 
 from repro.launch.hlo_analysis import analyze as analyze_hlo
@@ -111,7 +111,7 @@ def build_cell(arch: str, shape_name: str, mesh, *, fsdp: bool = True,
         # gradient accumulation for the giants: activations scale with the
         # microbatch; fp32 grad accumulator is FSDP-sharded
         accum = 4 if big else 1
-        aopt = jax.eval_shape(partial(adamw.init, opt_cfg), aparams)
+        aopt = jax.eval_shape(partial(init_opt_state, opt_cfg), aparams)
         o_sh = opt_state_shardings(aopt, mesh, dp, tp, fsdp)
         # explicit out_shardings: without them the partitioner may produce
         # REPLICATED grads (all-reduce) instead of reduce-scattering into

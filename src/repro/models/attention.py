@@ -7,6 +7,7 @@ Each layer exposes:
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional
 
 import jax
@@ -92,7 +93,8 @@ def gqa_decode(cfg: ModelConfig, p, x, k_cache, v_cache, cache_index, *,
 # ===========================================================================
 # MLA (multi-head latent attention, deepseek-v3)
 #
-# q: d -> q_lora -> H*(nope+rope); kv: d -> (kv_lora + rope_shared);
+# q: d -> q_lora -> H*(nope+rope), or d -> H*(nope+rope) directly when
+# q_lora_rank is 0 (Moonlight); kv: d -> (kv_lora + rope_shared);
 # decode cache stores only the compressed latent + shared rope key.
 # ===========================================================================
 def mla_init(key, cfg: ModelConfig, dtype):
@@ -100,10 +102,14 @@ def mla_init(key, cfg: ModelConfig, dtype):
     qr, kvr = cfg.q_lora_rank, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
     ks = jax.random.split(key, 6)
+    if qr:
+        q = {"wq_a": dense_init(ks[0], (d, qr), dtype),
+             "q_norm": jnp.ones((qr,), dtype),
+             "wq_b": dense_init(ks[1], (qr, H * (dn + dr)), dtype)}
+    else:  # no q-LoRA: one direct projection
+        q = {"wq": dense_init(ks[0], (d, H * (dn + dr)), dtype)}
     return {
-        "wq_a": dense_init(ks[0], (d, qr), dtype),
-        "q_norm": jnp.ones((qr,), dtype),
-        "wq_b": dense_init(ks[1], (qr, H * (dn + dr)), dtype),
+        **q,
         "wkv_a": dense_init(ks[2], (d, kvr + dr), dtype),
         "kv_norm": jnp.ones((kvr,), dtype),
         "wkv_b": dense_init(ks[3], (kvr, H * (dn + dv)), dtype),
@@ -115,7 +121,10 @@ def _mla_q(cfg, p, x, positions, ctx):
     B, S, _ = x.shape
     H = cfg.num_heads
     dn, dr = cfg.qk_nope_dim, cfg.qk_rope_dim
-    q = rms_norm(x @ p["wq_a"], p["q_norm"], cfg.norm_eps) @ p["wq_b"]
+    if "wq" in p:
+        q = x @ p["wq"]
+    else:
+        q = rms_norm(x @ p["wq_a"], p["q_norm"], cfg.norm_eps) @ p["wq_b"]
     q = q.reshape(B, S, H, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
     q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
@@ -146,16 +155,43 @@ def mla_apply(cfg: ModelConfig, p, x, *, positions, causal: bool,
               ctx: Optional[ShardCtx]):
     B, S, _ = x.shape
     dn, dr = cfg.qk_nope_dim, cfg.qk_rope_dim
-    q = _mla_q(cfg, p, x, positions, ctx)
-    latent = x @ p["wkv_a"]  # (B, S, kv_lora + rope)
-    k_rope = apply_rope(latent[..., cfg.kv_lora_rank:][:, :, None, :],
-                        positions, cfg.rope_theta)[:, :, 0, :]
-    latent = jnp.concatenate([latent[..., :cfg.kv_lora_rank], k_rope], -1)
-    k, v = _mla_kv_from_latent(cfg, p, latent, ctx)
-    o = flash_attention(q, k, v, causal=causal, scale=(dn + dr) ** -0.5,
+    with jax.named_scope("mla.q"):
+        q = _mla_q(cfg, p, x, positions, ctx)
+    with jax.named_scope("mla.kv"):
+        latent = x @ p["wkv_a"]  # (B, S, kv_lora + rope)
+        k_rope = apply_rope(latent[..., cfg.kv_lora_rank:][:, :, None, :],
+                            positions, cfg.rope_theta)[:, :, 0, :]
+        latent = jnp.concatenate([latent[..., :cfg.kv_lora_rank], k_rope],
+                                 -1)
+        k, v = _mla_kv_from_latent(cfg, p, latent, ctx)
+    with jax.named_scope("mla.core"):
+        o = _mla_attend(q, k, v, causal=causal, scale=(dn + dr) ** -0.5,
                         ctx=ctx)
-    out = o.reshape(B, S, cfg.num_heads * cfg.v_head_dim) @ p["wo"]
+        out = o.reshape(B, S, cfg.num_heads * cfg.v_head_dim) @ p["wo"]
     return constrain(out, ctx, "dp", "tp", None)
+
+
+#: queries a causal, unsharded MLA attention takes at a time
+Q_CHUNK = 2048
+
+
+def _mla_attend(q, k, v, *, causal: bool, scale: float,
+                ctx: Optional[ShardCtx]):
+    """flash_attention, in causal query chunks past Q_CHUNK: chunk i reads
+    keys [0, (i + 1) Q_CHUNK) only, and each chunk is rematerialized on
+    its own, so the backward pass holds one chunk's kv-block carries
+    (<= 1.1 GB at 4 x 8192 x 16 heads) and not the whole sequence's
+    (4.3 GB)."""
+    S = q.shape[1]
+    if ctx is not None or not causal or S <= Q_CHUNK or S % Q_CHUNK:
+        return flash_attention(q, k, v, causal=causal, scale=scale, ctx=ctx)
+    outs = []
+    for lo in range(0, S, Q_CHUNK):
+        hi = lo + Q_CHUNK
+        chunk = jax.checkpoint(partial(flash_attention, causal=True,
+                                       q_offset=lo, scale=scale))
+        outs.append(chunk(q[:, lo:hi], k[:, :hi], v[:, :hi]))
+    return jnp.concatenate(outs, 1)
 
 
 def mla_decode(cfg: ModelConfig, p, x, kv_cache, cache_index, *,

@@ -84,6 +84,7 @@ def _sp(x, ctx):
 
 def block_apply(cfg: ModelConfig, p, x, positions, ctx, *, moe: bool,
                 causal: bool = True):
+    """-> (x, routing stats of an MoE layer, None for a dense one)."""
     # norm outputs pinned to SP: the attention/MLP full-sequence gather
     # then moves to the bf16 tensor instead of the f32 rms upcast
     with jax.named_scope("attention"):
@@ -97,11 +98,13 @@ def block_apply(cfg: ModelConfig, p, x, positions, ctx, *, moe: bool,
         x = _sp(x + a, ctx)
     with jax.named_scope("mlp"):
         h = _sp(rms_norm(x, p["norm2"], cfg.norm_eps), ctx)
+        stats = None
         if moe:
-            m = moe_mod.moe_apply(cfg, p["mlp"], h, ctx)
+            m, stats = moe_mod.moe_apply(cfg, p["mlp"], h, ctx,
+                                         router_stats=True)
         else:
             m = moe_mod.mlp_apply(cfg, p["mlp"], h, ctx)
-        return _sp(x + m, ctx)
+        return _sp(x + m, ctx), stats
 
 
 def _remat(fn, cfg: ModelConfig):
@@ -114,12 +117,12 @@ def _remat(fn, cfg: ModelConfig):
 
 
 def scan_stack(cfg: ModelConfig, stacked, x, positions, ctx, *, moe: bool):
+    """-> (x, each layer's routing stats stacked, None for dense layers)."""
     def body(carry, p_layer):
-        return block_apply(cfg, p_layer, carry, positions, ctx, moe=moe), None
+        return block_apply(cfg, p_layer, carry, positions, ctx, moe=moe)
 
     body = _remat(body, cfg)
-    x, _ = jax.lax.scan(body, x, stacked)
-    return x
+    return jax.lax.scan(body, x, stacked)
 
 
 # ---------------------------------------------------------------------------
@@ -136,24 +139,28 @@ def embed_inputs(cfg: ModelConfig, params, batch, ctx):
 
 
 def forward(cfg: ModelConfig, params, batch, ctx: Optional[ShardCtx] = None,
-            return_hidden: bool = False):
-    """Full-sequence forward -> logits (B, S, V)."""
+            return_hidden: bool = False, return_stats: bool = False):
+    """Full-sequence forward -> logits (B, S, V), then the final hidden
+    state with `return_hidden`, then the MoE layers' routing stats stacked
+    over layers (`moe.routing_stats`; None without MoE layers) with
+    `return_stats`."""
     with jax.named_scope("embed"):
         x = embed_inputs(cfg, params, batch, ctx)
     S = x.shape[1]
     positions = jnp.arange(S)
-    n_dense = cfg.first_dense_layers if cfg.num_experts else cfg.num_layers
+    stats = None
     if "dense_layers" in params:
-        x = scan_stack(cfg, params["dense_layers"], x, positions, ctx,
-                       moe=False)
+        x, _ = scan_stack(cfg, params["dense_layers"], x, positions, ctx,
+                          moe=False)
     if "moe_layers" in params:
-        x = scan_stack(cfg, params["moe_layers"], x, positions, ctx, moe=True)
+        x, stats = scan_stack(cfg, params["moe_layers"], x, positions, ctx,
+                              moe=True)
     with jax.named_scope("logits"):
         h = rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = lm_logits(cfg, params, h, ctx)
-    if return_hidden:
-        return logits, h
-    return logits
+    out = (logits,) + ((h,) if return_hidden else ()) + (
+        (stats,) if return_stats else ())
+    return out if len(out) > 1 else logits
 
 
 def lm_logits(cfg: ModelConfig, params, h, ctx):
@@ -175,8 +182,8 @@ def mtp_logits(cfg: ModelConfig, params, h, batch, ctx):
     z = jnp.concatenate([rms_norm(h, p["norm"], cfg.norm_eps), nxt], -1)
     z = _sp(z @ p["proj"], ctx)
     S = z.shape[1]
-    z = block_apply(cfg, p["block"], z, jnp.arange(S), ctx,
-                    moe=bool(cfg.num_experts))
+    z, _ = block_apply(cfg, p["block"], z, jnp.arange(S), ctx,
+                       moe=bool(cfg.num_experts))
     return lm_logits(cfg, params, rms_norm(z, params["final_norm"],
                                            cfg.norm_eps), ctx)
 

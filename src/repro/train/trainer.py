@@ -18,6 +18,7 @@ import jax
 import numpy as np
 
 from repro.configs.base import ModelConfig, ShapeSpec
+from repro.core import spans
 from repro.core.ofu import ofu_point
 from repro.core.peaks import ChipSpec, device_chip
 from repro.data.pipeline import synthetic_batch
@@ -25,7 +26,7 @@ from repro.fleet.recovery import RecoveryService, StragglerMonitor
 from repro.models import api as models
 from repro.optim import adamw
 from repro.train import checkpoint as ckpt
-from repro.train.steps import make_train_step
+from repro.train.steps import init_opt_state, make_train_step
 
 
 @dataclass
@@ -95,7 +96,7 @@ class Trainer:
 
     def _init_state(self):
         params = models.init_params(self.cfg, jax.random.key(self.tc.seed))
-        opt_state = adamw.init(self.opt_cfg, params)
+        opt_state = init_opt_state(self.opt_cfg, params)
         return params, opt_state
 
     def _telemetry(self, step: int, dt: float) -> StepTelemetry:
@@ -139,6 +140,9 @@ class Trainer:
                 jax.block_until_ready(m["loss"])
                 dt = time.perf_counter() - t0
                 step += 1
+                if "moe_held_pairs" in m and spans.recording():
+                    spans.count("moe.held_pairs", int(m["moe_held_pairs"]))
+                    spans.count("moe.steps")
 
                 tel = self._telemetry(step, dt)
                 self.history.append(tel)

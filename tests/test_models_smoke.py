@@ -11,11 +11,12 @@ from repro.configs import get_config, list_configs, make_inputs
 from repro.configs.base import ShapeSpec
 from repro.models import decode_step, forward, init_params
 from repro.optim import adamw
-from repro.train.steps import make_train_step
+from repro.train.steps import init_opt_state, make_train_step
 
 ARCHS = ["deepseek-moe-16b", "deepseek-v3-671b", "qwen3-4b",
          "nemotron-4-340b", "granite-3-2b", "llama3.2-3b", "whisper-small",
-         "phi-3-vision-4.2b", "mamba2-780m", "zamba2-7b"]
+         "phi-3-vision-4.2b", "mamba2-780m", "zamba2-7b",
+         "moonlight-16b-a3b"]
 
 
 def test_all_assigned_archs_registered():
@@ -57,7 +58,7 @@ def test_train_step_smoke(arch):
     cfg, params = _state(arch)
     # lr large enough that one update survives bf16 weight quantization
     opt_cfg = adamw.OptConfig(peak_lr=0.05, warmup_steps=1, decay_steps=10)
-    opt_state = adamw.init(opt_cfg, params)
+    opt_state = init_opt_state(opt_cfg, params)
     step = jax.jit(make_train_step(cfg, opt_cfg))
     batch = {k: jnp.asarray(v)
              for k, v in make_inputs(cfg, ShapeSpec("t", 32, 2, "train")).items()}

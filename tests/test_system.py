@@ -199,10 +199,12 @@ def test_moe_routing_finite_and_balanced(seed):
     p = moe_init(jax.random.key(seed % 1000), cfg, jnp.float32)
     x = jnp.asarray(rng.standard_normal((2, 16, cfg.d_model)) * 0.5,
                     jnp.float32)
-    y, aux = moe_apply(cfg, p, x, None, router_stats=True)
+    y, stats = moe_apply(cfg, p, x, None, router_stats=True)
     assert y.shape == x.shape
     assert np.isfinite(np.asarray(y)).all()
-    assert float(aux) >= 0.9  # load-balance loss >= ~1 at uniform
+    # sequence-wise balance term sum_i f_i P_i: 1 when balanced
+    assert float(stats["balance"]) >= 0.9
+    assert float(stats["load"].sum()) == 2 * 16 * cfg.top_k
 
 
 def test_moe_decode_single_group_matches_batched():
