@@ -7,7 +7,6 @@ Each layer exposes:
 """
 from __future__ import annotations
 
-from functools import partial
 from typing import Optional
 
 import jax
@@ -178,20 +177,17 @@ Q_CHUNK = 2048
 def _mla_attend(q, k, v, *, causal: bool, scale: float,
                 ctx: Optional[ShardCtx]):
     """flash_attention, in causal query chunks past Q_CHUNK: chunk i reads
-    keys [0, (i + 1) Q_CHUNK) only, and each chunk is rematerialized on
-    its own, so the backward pass holds one chunk's kv-block carries
-    (<= 1.1 GB at 4 x 8192 x 16 heads) and not the whole sequence's
-    (4.3 GB)."""
+    keys [0, (i + 1) Q_CHUNK) only, so the kv blocks past its diagonal are
+    never computed.  Each chunk's backward keeps its output and log-sum-exp
+    beside the q, k and v the chunks share, so they need no remat of their
+    own."""
     S = q.shape[1]
     if ctx is not None or not causal or S <= Q_CHUNK or S % Q_CHUNK:
         return flash_attention(q, k, v, causal=causal, scale=scale, ctx=ctx)
-    outs = []
-    for lo in range(0, S, Q_CHUNK):
-        hi = lo + Q_CHUNK
-        chunk = jax.checkpoint(partial(flash_attention, causal=True,
-                                       q_offset=lo, scale=scale))
-        outs.append(chunk(q[:, lo:hi], k[:, :hi], v[:, :hi]))
-    return jnp.concatenate(outs, 1)
+    return jnp.concatenate(
+        [flash_attention(q[:, lo:lo + Q_CHUNK], k, v, causal=True,
+                         q_offset=lo, scale=scale)
+         for lo in range(0, S, Q_CHUNK)], 1)
 
 
 def mla_decode(cfg: ModelConfig, p, x, kv_cache, cache_index, *,

@@ -132,9 +132,11 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 # ---------------------------------------------------------------------------
 # flash attention, pure-jnp (blockwise online softmax, no S×S materialization)
 #
-# This is the dry-run / CPU path.  The Pallas kernel in repro.kernels is the
-# TPU fast path and is validated against repro.kernels.ref which shares this
-# math.
+# Every model path runs this: train, prefill and the dry-run.  The forward is
+# one scan over kv blocks; the backward is FlashAttention-2's, one scan over
+# the same blocks that recomputes each block's probabilities from the saved
+# per-row log-sum-exp.  The Pallas forward in repro.kernels is validated
+# against repro.kernels.ref, which shares this math.
 # ---------------------------------------------------------------------------
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool, q_offset=0,
@@ -144,8 +146,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     """q: (B, Sq, H, hd); k/v: (B, Sk, KV, hd) with H % KV == 0.
 
     q_offset: global position of q[:, 0] (for causal masking during decode /
-    chunked prefill).  kv_len: optional valid-length of k/v (decode caches).
-    Returns (B, Sq, H, hd_v).
+    chunked prefill); a causal call with an int offset reads only the keys
+    its last query sees.  kv_len: optional valid-length of k/v (decode
+    caches).  Returns (B, Sq, H, hd_v).
+
+    The gradient is a custom VJP: the forward keeps q, k, v as given, the
+    output and the per-row log-sum-exp (O(S) beyond what the caller holds,
+    no per-block state), and the backward recomputes each block's
+    probabilities from them once.
 
     Layout note: internally runs head-major (B, H, S, hd) with GQA KV heads
     repeated, and pins every scan carry to the head-sharded layout — without
@@ -158,6 +166,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     _, Sk, KV, hd_v = v.shape
     G = H // KV
     scale = hd ** -0.5 if scale is None else scale
+    Sr = Sk  # keys read
+    if causal and isinstance(q_offset, int):
+        Sr = min(Sk, max(q_offset + Sq, 1))
+    block = min(block, Sr)
+    nb = -(-Sr // block)
+    pad = nb * block - Sr
 
     shard_heads = head_shardable(H, ctx)
 
@@ -166,27 +180,34 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             return x
         return constrain(x, ctx, *(("dp", "tp") + (None,) * (x.ndim - 2)))
 
-    qh = pin(q.transpose(0, 2, 1, 3))                      # (B, H, Sq, hd)
-    if G > 1:  # repeat KV heads -> clean head sharding
-        k = jnp.repeat(k, G, axis=2)
-        v = jnp.repeat(v, G, axis=2)
-    kh = pin(k.transpose(0, 2, 1, 3))                      # (B, H, Sk, hd)
-    vh = pin(v.transpose(0, 2, 1, 3))
+    def head_major(q, k, v):
+        """-> q (B, H, Sq, hd); k, v as (nb, B, H, block, hd) blocks."""
+        qh = pin(q.transpose(0, 2, 1, 3))
+        k, v = k[:, :Sr], v[:, :Sr]
+        if G > 1:  # repeat KV heads -> clean head sharding
+            k = jnp.repeat(k, G, axis=2)
+            v = jnp.repeat(v, G, axis=2)
+        kh = pin(k.transpose(0, 2, 1, 3))                  # (B, H, Sr, hd)
+        vh = pin(v.transpose(0, 2, 1, 3))
+        if pad:
+            kh = jnp.pad(kh, ((0, 0), (0, 0), (0, pad), (0, 0)))
+            vh = jnp.pad(vh, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        blocks = lambda x: jnp.moveaxis(
+            x.reshape(B, H, nb, block, x.shape[-1]), 2, 0)
+        return qh, blocks(kh), blocks(vh)
 
-    block = min(block, Sk)
-    nb = -(-Sk // block)
-    pad = nb * block - Sk
-    if pad:
-        kh = jnp.pad(kh, ((0, 0), (0, 0), (0, pad), (0, 0)))
-        vh = jnp.pad(vh, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    kb = jnp.moveaxis(kh.reshape(B, H, nb, block, hd), 2, 0)
-    vb = jnp.moveaxis(vh.reshape(B, H, nb, block, hd_v), 2, 0)
+    def kv_major(xb, dtype):
+        """The gradient of head_major's k or v blocks -> (B, Sk, KV, d)."""
+        x = jnp.moveaxis(xb, 0, 2).reshape(B, H, nb * block, xb.shape[-1])
+        x = x[:, :, :Sr].transpose(0, 2, 1, 3)
+        if G > 1:  # the repeat's gradient: sum over each KV head's G copies
+            x = x.reshape(B, Sr, KV, G, x.shape[-1]).astype(
+                jnp.float32).sum(3)
+        return jnp.pad(x.astype(dtype),
+                       ((0, 0), (0, Sk - Sr), (0, 0), (0, 0)))
 
-    q_pos = q_offset + jnp.arange(Sq)
-
-    def body(carry, inputs):
-        m, l, acc = carry
-        kj, vj, j = inputs
+    def scores(qh, kj, j, q_pos, kv_len):
+        """One block's masked f32 scores, (B, H, Sq, block)."""
         s = pin(jnp.einsum("bhqd,bhjd->bhqj", qh, kj,
                            preferred_element_type=jnp.float32) * scale)
         kv_pos = j * block + jnp.arange(block)
@@ -196,33 +217,95 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         if kv_len is not None:
             mask &= kv_pos[None, :] < kv_len
         if pad:
-            mask &= kv_pos[None, :] < Sk
-        s = jnp.where(mask[None, None], s, -jnp.inf)
-        m_new = jnp.maximum(m, s.max(-1))
-        # guard fully-masked rows (m_new == -inf)
-        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        # no second mask on p: exp(-inf - finite) is already 0, and each
-        # avoided (B,H,Sq,block) write is ~160 GiB/step on a 40-layer train
-        p = jnp.exp(s - m_safe[..., None])
-        corr = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
-        l_new = pin(l * corr + p.sum(-1))
-        acc_new = pin(acc * corr[..., None] + jnp.einsum(
-            "bhqj,bhjd->bhqd", p.astype(vj.dtype), vj,
-            preferred_element_type=jnp.float32))
-        return (pin(m_new), l_new, acc_new), None
+            mask &= kv_pos[None, :] < Sr
+        return jnp.where(mask[None, None], s, -jnp.inf)
 
-    m0 = pin(jnp.full((B, H, Sq), -jnp.inf, jnp.float32))
-    l0 = pin(jnp.zeros((B, H, Sq), jnp.float32))
-    a0 = pin(jnp.zeros((B, H, Sq, hd_v), jnp.float32))
-    # remat per kv-block: without this the backward pass saves the (Sq ×
-    # block) probability tensor for EVERY iteration (flash-bwd recomputes
-    # them blockwise instead — that is the whole point of flash attention)
-    body = jax.checkpoint(body,
-                          policy=jax.checkpoint_policies.nothing_saveable)
-    (m, l, acc), _ = jax.lax.scan(
-        body, (m0, l0, a0), (kb, vb, jnp.arange(nb)))
-    out = acc / jnp.maximum(l, 1e-37)[..., None]           # (B, H, Sq, hd_v)
-    return out.transpose(0, 2, 1, 3).astype(q.dtype)
+    def forward(q, k, v, q_offset, kv_len):
+        """-> (out (B, Sq, H, hd_v) in q's dtype, lse (B, H, Sq) f32)."""
+        qh, kb, vb = head_major(q, k, v)
+        q_pos = q_offset + jnp.arange(Sq)
+
+        def body(carry, inputs):
+            m, l, acc = carry
+            kj, vj, j = inputs
+            s = scores(qh, kj, j, q_pos, kv_len)
+            m_new = jnp.maximum(m, s.max(-1))
+            # guard fully-masked rows (m_new == -inf)
+            m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+            # no second mask on p: exp(-inf - finite) is already 0, and each
+            # avoided (B,H,Sq,block) write is ~160 GiB/step on a 40-layer
+            # train
+            p = jnp.exp(s - m_safe[..., None])
+            corr = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
+            l_new = pin(l * corr + p.sum(-1))
+            acc_new = pin(acc * corr[..., None] + jnp.einsum(
+                "bhqj,bhjd->bhqd", p.astype(vj.dtype), vj,
+                preferred_element_type=jnp.float32))
+            return (pin(m_new), l_new, acc_new), None
+
+        m0 = pin(jnp.full((B, H, Sq), -jnp.inf, jnp.float32))
+        l0 = pin(jnp.zeros((B, H, Sq), jnp.float32))
+        a0 = pin(jnp.zeros((B, H, Sq, hd_v), jnp.float32))
+        # nothing differentiates this scan, but an enclosing rematted layer
+        # scan partially evaluates it: without the checkpoint it hoists each
+        # block's mask, broadcast to (B, H, Sq, block), out of the loop as
+        # one stacked residual (1 GiB a 2048-query chunk of an 8K MLA)
+        body = jax.checkpoint(body,
+                              policy=jax.checkpoint_policies.nothing_saveable)
+        (m, l, acc), _ = jax.lax.scan(
+            body, (m0, l0, a0), (kb, vb, jnp.arange(nb)))
+        out = acc / jnp.maximum(l, 1e-37)[..., None]       # (B, H, Sq, hd_v)
+        return out.transpose(0, 2, 1, 3).astype(q.dtype), pin(m + jnp.log(l))
+
+    @jax.custom_vjp
+    def attend(q, k, v, q_offset, kv_len):
+        with jax.named_scope("attention.flash_fwd"):
+            return forward(q, k, v, q_offset, kv_len)[0]
+
+    def attend_fwd(q, k, v, q_offset, kv_len):
+        with jax.named_scope("attention.flash_fwd"):
+            out, lse = forward(q, k, v, q_offset, kv_len)
+        return out, (q, k, v, out, lse, q_offset, kv_len)
+
+    def attend_bwd(res, dout):
+        q, k, v, out, lse, q_offset, kv_len = res
+        with jax.named_scope("attention.flash_bwd"):
+            # the barrier keeps XLA from sharing the forward's head-major
+            # copies, which would then live through the rest of the layer's
+            # backward: the residuals are q, k and v as the caller holds them
+            qh, kb, vb = head_major(*jax.lax.optimization_barrier((q, k, v)))
+            q_pos = q_offset + jnp.arange(Sq)
+            do = pin(dout.transpose(0, 2, 1, 3))            # (B, H, Sq, hd_v)
+            delta = pin(jnp.sum(dout.astype(jnp.float32)
+                                * out.astype(jnp.float32), -1)
+                        .transpose(0, 2, 1))                # (B, H, Sq)
+            # fully-masked rows (lse == -inf) get p = exp(-inf) = 0
+            lse = jnp.where(jnp.isfinite(lse), lse, jnp.inf)
+
+            def body(dq, inputs):
+                kj, vj, j = inputs
+                p = jnp.exp(scores(qh, kj, j, q_pos, kv_len) - lse[..., None])
+                dv = jnp.einsum("bhqj,bhqd->bhjd", p.astype(vj.dtype), do,
+                                preferred_element_type=jnp.float32)
+                dp = pin(jnp.einsum("bhqd,bhjd->bhqj", do, vj,
+                                    preferred_element_type=jnp.float32))
+                ds = p * (dp - delta[..., None]) * scale
+                dq = pin(dq + jnp.einsum(
+                    "bhqj,bhjd->bhqd", ds.astype(kj.dtype), kj,
+                    preferred_element_type=jnp.float32))
+                dk = jnp.einsum("bhqj,bhqd->bhjd", ds.astype(qh.dtype), qh,
+                                preferred_element_type=jnp.float32)
+                return dq, (pin(dk.astype(kj.dtype)),
+                            pin(dv.astype(vj.dtype)))
+
+            dq0 = pin(jnp.zeros((B, H, Sq, hd), jnp.float32))
+            dq, (dkb, dvb) = jax.lax.scan(body, dq0, (kb, vb, jnp.arange(nb)))
+            return (dq.transpose(0, 2, 1, 3).astype(q.dtype),
+                    kv_major(dkb, k.dtype), kv_major(dvb, v.dtype),
+                    None, None)
+
+    attend.defvjp(attend_fwd, attend_bwd)
+    return attend(q, k, v, q_offset, kv_len)
 
 
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
