@@ -1,15 +1,8 @@
 """Shared benchmark plumbing: each benchmark module exposes run() -> rows,
 where a row is (name, us_per_call, derived) — us_per_call times the core
-operation, derived carries the paper-comparable numbers.
-
-Suites that publish machine-readable results share `BENCH_fleet.json`
-(one file, merged BY CASE NAME so whichever suite runs second never
-clobbers the other's rows): record cases with `bench_case` and flush
-with `merge_bench_json`."""
+operation, derived carries the paper-comparable numbers."""
 from __future__ import annotations
 
-import json
-import os
 import time
 from dataclasses import dataclass
 
@@ -33,37 +26,3 @@ def timed(fn, *args, repeat: int = 3, **kw):
         out = fn(*args, **kw)
         best = min(best, time.perf_counter() - t0)
     return out, best * 1e6
-
-
-def bench_case(cases: list, name: str, median: float, units: str,
-               **metrics) -> None:
-    """Record one benchmark case: print the BENCH json line (the driver
-    greps for it) and append the structured row to `cases` for
-    `merge_bench_json`."""
-    print("BENCH " + json.dumps({"name": name, **metrics}))
-    cases.append({"name": name, "median": median, "units": units,
-                  "metrics": metrics})
-
-
-def merge_bench_json(cases: list, *, suite: str = "fleet_engine") -> str:
-    """Merge `cases` into BENCH_fleet.json BY NAME (path overridable via
-    the BENCH_FLEET_JSON env var).  Several suites share the file —
-    fleet_engine, the scenario scorecard, production_correlation — and
-    whichever runs second must not clobber the others' rows."""
-    path = os.environ.get("BENCH_FLEET_JSON", "BENCH_fleet.json")
-    doc = {"schema": 1, "suite": suite, "cases": []}
-    if os.path.exists(path):
-        try:
-            with open(path) as f:
-                prev = json.load(f)
-            if isinstance(prev.get("cases"), list):
-                doc = prev
-        except (json.JSONDecodeError, OSError):
-            pass                 # corrupt file: rewrite from scratch
-    fresh = {c["name"] for c in cases}
-    doc["cases"] = [c for c in doc["cases"]
-                    if c.get("name") not in fresh] + cases
-    with open(path, "w") as f:
-        json.dump(doc, f, indent=2)
-        f.write("\n")
-    return path

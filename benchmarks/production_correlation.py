@@ -10,19 +10,14 @@ half of the correlation story: batch rollups + `divergence.analyze` +
 `correlation.analyze_correlation`.  `tools/fleet_correlate.py
 --self-check` replays the SAME fixture through a live Collector and the
 HTTP serve path and asserts the numbers match bucketwise.
-
-Emits a `production_correlation` case into `BENCH_fleet.json` with the
-headline numbers (r before/after exclusion, flagged counts, MAE).
 """
 from __future__ import annotations
 
-from benchmarks.common import Row, bench_case, merge_bench_json, timed
+from benchmarks.common import Row, timed
 from repro.fleet import table3
 from repro.fleet.correlation import analyze_correlation
 from repro.fleet.divergence import analyze
 from repro.fleet.jobs import JobSpec, simulate_job
-
-_CASES: list[dict] = []
 
 
 def build_fleet(seed: int = 0):
@@ -70,19 +65,6 @@ def run() -> list[Row]:
         f"exact_match={cflagged == affected} "
         f"mae={crep.mae * 100:.1f}pp"))
 
-    bench_case(
-        _CASES, "production_correlation", round(crep.r_clean, 3),
-        "pearson_r",
-        jobs=crep.n_jobs,
-        r_all=round(crep.r_all, 3),
-        r_after_exclusion=round(crep.r_clean, 3),
-        flagged=len(cflagged),
-        affected=len(affected),
-        exact_match=bool(cflagged == affected and flagged == affected),
-        mae_pp=round(crep.mae * 100, 2),
-        build_wall_s=round(us / 1e6, 3),
-    )
-
     # ---- §V-C case studies (before/after FLOPs-counter fixes) ----
     moe_bad = simulate_job(JobSpec("cs1", "deepseek-v3-671b", chips=288,
                                    flops_variant="naive_moe", true_duty=0.26,
@@ -110,8 +92,6 @@ def run() -> list[Row]:
         f"fixed_mfu={hyb_fix.app_mfu * 100:.2f}% "
         f"fixed_rel_err={abs(hyb_fix.app_mfu - hyb_fix.ofu) / hyb_fix.ofu * 100:.1f}%"))
 
-    path = merge_bench_json(_CASES)
-    print(f"BENCH-JSON {path} cases={len(_CASES)}")
     return rows
 
 

@@ -7,7 +7,6 @@ Prints ``name,us_per_call,derived`` CSV.  Modules:
   prediction_accuracy    Table II / Fig. 4 (OFU vs Adjusted OFU accuracy)
   production_correlation Fig. 5 / Table III / SecV-C (608-job fleet)
   operational            Fig. 6 / Fig. 7 / SecVI-C (case studies)
-  fleet_engine           scalar-vs-vectorized simulation throughput
   roofline               assigned-arch roofline table (needs dry-run JSONs)
 """
 import sys
@@ -17,13 +16,12 @@ import traceback
 def main() -> None:
     from repro.launch.cache import enable_compile_cache
     enable_compile_cache()
-    from benchmarks import (clock_sampling, fleet_engine, operational,
-                            precision_scaling, prediction_accuracy,
-                            production_correlation, roofline,
-                            tile_quantization)
+    from benchmarks import (clock_sampling, operational, precision_scaling,
+                            prediction_accuracy, production_correlation,
+                            roofline, tile_quantization)
     mods = [tile_quantization, precision_scaling, clock_sampling,
             prediction_accuracy, production_correlation, operational,
-            fleet_engine, roofline]
+            roofline]
     only = sys.argv[1] if len(sys.argv) > 1 else None
     print("name,us_per_call,derived")
     failures = 0

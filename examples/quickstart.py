@@ -23,9 +23,8 @@ OFU percentiles:
     series = roll.job_ofu("job0")            # feed to detect_regressions
     p50 = roll.fleet_stats().percentiles[50]  # bucketed fleet median
 
-`simulate_fleet(..., engine="scalar")` selects the per-device reference
-backend instead; `benchmarks/fleet_engine.py` measures the gap (~45x on
-a laptop core, ~15M simulated device-seconds per wall-second).  See
+`simulate_fleet(..., engine="jax")` runs the same generative model on
+the jax device path instead of the NumPy reference.  See
 examples/fleet_monitoring.py for the full §V/§VI monitoring loop.
 """
 import argparse

@@ -22,9 +22,12 @@ as batched NumPy array ops, at two fusion levels:
     skip the duty sub-sample grid entirely (their deterministic duty is
     constant in time); evented jobs evaluate it device-batched.
 
-The scalar backend remains the reference implementation; equivalence is
-statistical (same seed/profile ⇒ matching tpa/clock statistics within
-tolerance), covered by tests/test_fleet_engine.py.
+Each group's host half (layout, event factors, n_eff/n_sub, duty bases,
+OU drive) is one `GroupPlan`, shared with the jax backend
+(`engine_jax._group_inputs`).  The per-device `SimulatedDeviceBackend`
+remains the semantic oracle; equivalence is statistical (same
+seed/profile ⇒ matching tpa/clock statistics within tolerance), covered
+by tests/test_fleet_engine.py.
 """
 from __future__ import annotations
 
@@ -80,9 +83,9 @@ class CounterFault:
     CounterFault instead perturbs the OBSERVED counters after the engine
     pass — multiplicative masks over the (device, sample) grid — which is
     what the scenario library needs for ground-truth labels: the
-    perturbation applies identically on every backend (scalar, vector,
-    fused, jax), so a detector scorecard measures the detector, never
-    engine-equivalence noise.
+    perturbation applies identically on both engines (fused, jax), so a
+    detector scorecard measures the detector, never engine-equivalence
+    noise.
 
     Timeline: active on samples with start_s <= t < end_s.  period_s > 0
     gates that window into repeating bursts (active for the first
@@ -245,82 +248,128 @@ def group_slots(slots: Sequence[JobSlot]) -> dict:
     return groups
 
 
-def _simulate_group(members, out, rng, params: EngineParams) -> None:
-    """One fused pass over all jobs sharing an interval + clock model."""
+def group_layout(members) -> tuple[float, list, np.ndarray]:
+    """(scrape interval, each member's straggler factors, each member's
+    sample count) of one group from `group_slots`."""
     interval = float(members[0][1].interval_s)
-    cm = members[0][2]
     strag_list = [np.ones(1) if sl.stragglers is None
                   else np.atleast_1d(np.asarray(sl.stragglers, float))
                   for _, sl, _ in members]
-    n_dev = np.array([len(s) for s in strag_list])
     S = np.array([max(int(sl.duration_s / interval), 0)
                   for _, sl, _ in members])
-    S_max = int(S.max())
-    if S_max <= 0:
-        for (i, _, _), st in zip(members, strag_list):
-            out[i] = DeviceGrid(interval, np.empty((len(st), 0)),
-                                np.empty((len(st), 0)))
-        return
-    avg_w = check_scrape_interval(interval, strict=False)
+    return interval, strag_list, S
 
+
+def empty_grids(members, out, interval: float, strag_list) -> None:
+    """A (rows, 0) grid for every member of a group with no whole sample."""
+    for (i, _, _), st in zip(members, strag_list):
+        out[i] = DeviceGrid(interval, np.empty((len(st), 0)),
+                            np.empty((len(st), 0)))
+
+
+@dataclass
+class GroupPlan:
+    """The host half of one fused group, computed once by `group_plan`
+    and read by both evaluators: `_simulate_group` (NumPy) and
+    `engine_jax._group_inputs` (packing for the device program).  Draws
+    nothing; rows are device rows, members in group order."""
+
+    interval: float
+    n_dev: np.ndarray        # (J,) device rows per member
+    S: np.ndarray            # (J,) samples per member
+    dev_job: np.ndarray      # (D,) int32 row -> member
+    strag: np.ndarray        # (D,) f32 straggler factors
+    sig: np.ndarray          # (D,) f32 jitter sigma, jitter / n_eff
+    ratio: np.ndarray        # (J,) f32 full-rate duty, mxu / step
+    has_ev: np.ndarray       # (J,) member has events
+    n_sub: int               # window sub-samples of evented members (1: none)
+    ev_base: list            # per evented member: f32 (S_max, n_sub) duty base
+    base_end: np.ndarray     # (J, S_max) f32 window-end duty: the OU drive
+
+    @property
+    def S_max(self) -> int:
+        return self.base_end.shape[1]
+
+
+def group_plan(members, params: EngineParams) -> GroupPlan:
+    """Lay out one group (`group_slots` value, at least one sample) and
+    evaluate its deterministic duty: every event factor, the n_eff/n_sub
+    policy and the per-row factors, in f32 where the grids are f32."""
+    interval, strag_list, S = group_layout(members)
+    S_max = int(S.max())
+    avg_w = check_scrape_interval(interval, strict=False, stacklevel=4)
     J = len(members)
+    n_dev = np.array([len(s) for s in strag_list])
     step = np.array([sl.profile.step_time_s for _, sl, _ in members])
     mxu = np.array([sl.profile.mxu_time_s for _, sl, _ in members])
     jit = np.array([sl.profile.jitter for _, sl, _ in members])
     # same effective sub-sample count as the scalar backend (per job)
     n_eff = np.clip(avg_w / np.maximum(step / 4, 1e-3), 8, 4096).astype(int)
     has_ev = np.array([bool(sl.events) for _, sl, _ in members])
-    dev_job = np.repeat(np.arange(J), n_dev)          # (D,) row -> job
-    strag = np.concatenate(strag_list)                # (D,)
-    D = len(strag)
+    dev_job = np.repeat(np.arange(J, dtype=np.int32), n_dev)
     t_end = (np.arange(S_max) + 1.0) * interval
-
-    # --- duty: hardware-averaged over the trailing window -----------------
     # the whole tpa pipeline runs float32: counters are duty fractions in
     # [0, 1], so 1e-7 relative granularity is noise-free headroom, and the
     # grid passes move half the bytes
-    ratio = (mxu / step).astype(np.float32)           # full-rate duty (J,)
-    strag32 = strag.astype(np.float32)
-    tpa = np.empty((D, S_max), dtype=np.float32)
-    plain = ~has_ev[dev_job]
-    # no events -> deterministic duty is constant in time: skip the sub grid
-    tpa[plain] = np.minimum(np.float32(1.0), ratio[dev_job][plain]
-                            / strag32[plain])[:, None]
-    if has_ev.any():
-        ev_jobs = np.flatnonzero(has_ev)
+    ratio = (mxu / step).astype(np.float32)
+    base_end = np.broadcast_to(ratio[:, None], (J, S_max)).copy()
+    ev_jobs = np.flatnonzero(has_ev)
+    n_sub, ev_base = 1, []
+    if ev_jobs.size:
         n_sub = int(min(params.n_sub_max, n_eff[ev_jobs].max()))
         offs = (np.arange(n_sub) / n_sub) * avg_w
         ts = (t_end[:, None] - avg_w) + offs[None, :]  # (S_max, n_sub)
-        row_off = np.concatenate([[0], np.cumsum(n_dev)])
-        # one bounded (S_max, n_sub) base grid per evented job, device
-        # rows in bounded blocks — resident memory scales with neither
-        # job count nor device count
-        block = max(1, 2 ** 24 // (S_max * n_sub))
-        for j in ev_jobs:
-            slow, scale = event_factors(members[j][1].events, ts)
-            base_j = ((mxu[j] * scale)
-                      / (step[j] * slow)).astype(np.float32)
-            for b0 in range(row_off[j], row_off[j + 1], block):
-                rb = slice(b0, min(b0 + block, row_off[j + 1]))
-                duty = base_j[None, :, :] / strag32[rb, None, None]
-                np.minimum(duty, np.float32(1.0), out=duty)
-                tpa[rb] = duty.mean(axis=2, dtype=np.float32)
+    for j in ev_jobs:
+        events = members[j][1].events
+        slow, scale = event_factors(events, ts)
+        ev_base.append(((mxu[j] * scale)
+                        / (step[j] * slow)).astype(np.float32))
+        slow_e, scale_e = event_factors(events, t_end - 1e-6)
+        base_end[j] = (mxu[j] * scale_e) / (step[j] * slow_e)
+    return GroupPlan(interval, n_dev, S, dev_job,
+                     np.concatenate(strag_list).astype(np.float32),
+                     (jit / n_eff).astype(np.float32)[dev_job], ratio,
+                     has_ev, n_sub, ev_base, base_end)
+
+
+def _simulate_group(members, out, rng, params: EngineParams) -> None:
+    """One fused pass over all jobs sharing an interval + clock model."""
+    interval, strag_list, S = group_layout(members)
+    if int(S.max()) <= 0:
+        return empty_grids(members, out, interval, strag_list)
+    p = group_plan(members, params)
+    cm = members[0][2]
+    D, S_max = len(p.strag), p.S_max
+
+    # --- duty: hardware-averaged over the trailing window -----------------
+    tpa = np.empty((D, S_max), dtype=np.float32)
+    plain = ~p.has_ev[p.dev_job]
+    # no events -> deterministic duty is constant in time: skip the sub grid
+    tpa[plain] = np.minimum(np.float32(1.0), p.ratio[p.dev_job][plain]
+                            / p.strag[plain])[:, None]
+    # one bounded (S_max, n_sub) base grid per evented job, device rows in
+    # bounded blocks — resident memory scales with neither job count nor
+    # device count
+    row_off = np.concatenate([[0], np.cumsum(p.n_dev)])
+    block = max(1, 2 ** 24 // (S_max * p.n_sub))
+    for j, base_j in zip(np.flatnonzero(p.has_ev), p.ev_base):
+        for b0 in range(row_off[j], row_off[j + 1], block):
+            rb = slice(b0, min(b0 + block, row_off[j + 1]))
+            duty = base_j[None, :, :] / p.strag[rb, None, None]
+            np.minimum(duty, np.float32(1.0), out=duty)
+            tpa[rb] = duty.mean(axis=2, dtype=np.float32)
     # one lognormal draw per (device, sample) with the scalar path's
     # mean-of-n-jittered-subsamples dispersion (σ ≈ jitter / n_eff) —
     # a single shared stream for the whole group
     jitter = rng.standard_normal((D, S_max), dtype=np.float32)
-    jitter *= (jit / n_eff).astype(np.float32)[dev_job][:, None]
+    jitter *= p.sig[:, None]
     np.exp(jitter, out=jitter)
     tpa *= jitter
     np.clip(tpa, 0.0, 1.0, out=tpa)
 
     # --- clock: ONE batched OU pass for every device of every job ---------
-    base_end = np.broadcast_to(ratio[:, None], (J, S_max)).copy()
-    for j in np.flatnonzero(has_ev):
-        slow_e, scale_e = event_factors(members[j][1].events, t_end - 1e-6)
-        base_end[j] = (mxu[j] * scale_e) / (step[j] * slow_e)
-    duty_end = base_end[dev_job]
-    duty_end /= strag32[:, None]
+    duty_end = p.base_end[p.dev_job]
+    duty_end /= p.strag[:, None]
     np.minimum(duty_end, np.float32(1.0), out=duty_end)             # (D, S)
     # exact OU discretization: one step per scrape sample (the drive is
     # constant within each interval, so no sub-stepping is needed)
@@ -328,7 +377,7 @@ def _simulate_group(members, out, rng, params: EngineParams) -> None:
                               seed=int(rng.integers(0, 2 ** 31)))
 
     row0 = 0
-    for (i, _, _), nd, Sj in zip(members, n_dev, S):
+    for (i, _, _), nd, Sj in zip(members, p.n_dev, p.S):
         # copies (cheap vs the simulation) so holding one job's telemetry
         # never pins the whole group's padded arrays in memory
         out[i] = DeviceGrid(interval,

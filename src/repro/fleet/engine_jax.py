@@ -7,7 +7,8 @@ accelerators).  Same structure, device arrays instead of ndarrays:
 
   * jobs grouped by `engine.group_slots` — one padded (D, S_max) grid,
     one jitter draw, and one OU recurrence per (interval, clock-model)
-    group, exactly like the NumPy path;
+    group, exactly like the NumPy path, from the same host plan
+    (`engine.group_plan`);
   * evented duty averages the per-window sub-samples with a `lax.scan`
     over the n_sub axis, so resident memory stays O(D·S) however finely
     the hardware window is sub-sampled;
@@ -40,8 +41,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import spans
-from repro.fleet.engine import EngineParams, JobSlot, group_slots
-from repro.telemetry.counters import check_scrape_interval, event_factors
+from repro.fleet.engine import (EngineParams, JobSlot, empty_grids,
+                                group_layout, group_plan, group_slots)
 from repro.telemetry.scrape import DeviceGrid
 
 
@@ -115,26 +116,12 @@ def _group_device_sim(ratio, strag, dev_job, sig, ev_base, ev_rows,
     return tpa, f
 
 
-def _group_dims(members):
-    """(interval, per-member straggler factors, per-member sample counts)."""
-    interval = float(members[0][1].interval_s)
-    strag_list = [np.ones(1) if sl.stragglers is None
-                  else np.atleast_1d(np.asarray(sl.stragglers, float))
-                  for _, sl, _ in members]
-    S = np.array([max(int(sl.duration_s / interval), 0)
-                  for _, sl, _ in members])
-    return interval, strag_list, S
-
-
 def _simulate_group_jax(members, out, rng, params, mesh, materialize):
     """One fused group: host prep, one jitted device call, then one
     jitted split of its rows into a DeviceGrid per member."""
-    interval, strag_list, S = _group_dims(members)
+    interval, strag_list, S = group_layout(members)
     if int(S.max()) <= 0:
-        for (i, _, _), st in zip(members, strag_list):
-            out[i] = DeviceGrid(interval, np.empty((len(st), 0)),
-                                np.empty((len(st), 0)))
-        return
+        return empty_grids(members, out, interval, strag_list)
     with spans.span("engine.inputs"):
         args, static = _group_inputs(members, rng, params, mesh)
         args = [jnp.asarray(a) for a in args]
@@ -174,67 +161,37 @@ def _split_group(tpa, clock, starts, *, sizes: tuple):
 
 
 def _group_inputs(members, rng, params, mesh):
-    """Host half: mirrors `engine._simulate_group`'s prep (same event
-    factors, same n_eff/n_sub policy).  Returns the positional host
-    arrays and the static keywords of one `_group_device_sim` call."""
-    interval, strag_list, S = _group_dims(members)
-    S_max = int(S.max())
+    """Host half: packs the group's `engine.group_plan` for one
+    `_group_device_sim` call — rows padded to the mesh, the evented
+    bases stacked, the OU constants and two keys.  Returns the
+    positional host arrays and the static keywords."""
+    p = group_plan(members, params)
     cm = members[0][2]
-    n_dev = np.array([len(s) for s in strag_list])
-    avg_w = check_scrape_interval(interval, strict=False)
-
-    J = len(members)
-    step = np.array([sl.profile.step_time_s for _, sl, _ in members])
-    mxu = np.array([sl.profile.mxu_time_s for _, sl, _ in members])
-    jit = np.array([sl.profile.jitter for _, sl, _ in members])
-    n_eff = np.clip(avg_w / np.maximum(step / 4, 1e-3), 8, 4096).astype(int)
-    has_ev = np.array([bool(sl.events) for _, sl, _ in members])
-    dev_job = np.repeat(np.arange(J), n_dev).astype(np.int32)
-    strag = np.concatenate(strag_list).astype(np.float32)
-    # rows pad to a multiple of the mesh (copies of job 0, which the
-    # caller slices off) so every chip holds an equal share
+    dev_job, strag, sig = p.dev_job, p.strag, p.sig
+    # rows pad to a multiple of the mesh with copies of row 0, which the
+    # caller slices off, so every chip holds an equal share
     pad = -len(strag) % mesh.size if mesh is not None else 0
-    dev_job = np.concatenate([dev_job, np.zeros(pad, np.int32)])
-    strag = np.concatenate([strag, np.ones(pad, np.float32)])
-    t_end = (np.arange(S_max) + 1.0) * interval
-
-    ratio = (mxu / step).astype(np.float32)
-    sig = (jit / n_eff).astype(np.float32)[dev_job]
+    if pad:
+        dev_job, strag, sig = (np.concatenate([x, np.repeat(x[:1], pad)])
+                               for x in (dev_job, strag, sig))
 
     # per-window sub-sample base grids for evented jobs, (n_sub, J_e, S)
-    n_sub = 1
-    ev_rows = np.empty(0, np.int32)
-    ev_job_of_row = np.empty(0, np.int32)
-    ev_base = np.empty((1, 0, S_max), np.float32)
-    if has_ev.any():
-        ev_jobs = np.flatnonzero(has_ev)
-        n_sub = int(min(params.n_sub_max, n_eff[ev_jobs].max()))
-        offs = (np.arange(n_sub) / n_sub) * avg_w
-        ts = (t_end[:, None] - avg_w) + offs[None, :]   # (S_max, n_sub)
-        bases = []
-        for j in ev_jobs:
-            slow, scale = event_factors(members[j][1].events, ts)
-            bases.append(((mxu[j] * scale)
-                          / (step[j] * slow)).astype(np.float32).T)
-        ev_base = np.stack(bases, axis=1)               # (n_sub, J_e, S)
-        ev_rows = np.flatnonzero(has_ev[dev_job]).astype(np.int32)
-        job_to_e = np.cumsum(has_ev) - 1
-        ev_job_of_row = job_to_e[dev_job[ev_rows]].astype(np.int32)
+    ev_rows = ev_job_of_row = np.empty(0, np.int32)
+    ev_base = np.empty((1, 0, p.S_max), np.float32)
+    if p.ev_base:
+        ev_base = np.stack([b.T for b in p.ev_base], axis=1)
+        ev_rows = np.flatnonzero(p.has_ev[dev_job]).astype(np.int32)
+        ev_job_of_row = (np.cumsum(p.has_ev) - 1)[dev_job[ev_rows]] \
+            .astype(np.int32)
 
-    base_end = np.broadcast_to(ratio[:, None], (J, S_max)).copy()
-    for j in np.flatnonzero(has_ev):
-        slow_e, scale_e = event_factors(members[j][1].events, t_end - 1e-6)
-        base_end[j] = ((mxu[j] * scale_e) / (step[j] * slow_e)) \
-            .astype(np.float32)
-
-    a, sd = cm.ou_step_constants(interval)
+    a, sd = cm.ou_step_constants(p.interval)
     consts = (a, sd, cm.chip.f_max_mhz * cm.f_min_frac,
               float(cm.chip.f_max_mhz), cm.throttle_frac)
     k_jit, k_clk = (jax.random.PRNGKey(int(rng.integers(0, 2 ** 31)))
                     for _ in range(2))
-    args = (ratio, strag, dev_job, sig, ev_base, ev_rows, ev_job_of_row,
-            strag[ev_rows], base_end, k_jit, k_clk)
-    return args, dict(S=S_max, n_sub=n_sub, consts=consts, mesh=mesh)
+    args = (p.ratio, strag, dev_job, sig, ev_base, ev_rows, ev_job_of_row,
+            strag[ev_rows], p.base_end, k_jit, k_clk)
+    return args, dict(S=p.S_max, n_sub=p.n_sub, consts=consts, mesh=mesh)
 
 
 def simulate_jobs_jax(slots: Sequence[JobSlot], *, seed: int = 0,

@@ -21,9 +21,9 @@ from repro.core.ofu import effective_peak, ofu_mean
 from repro.core.peaks import DEFAULT_CHIP, ChipSpec
 from repro.core.tile_quant import pick_policy, profiled_flops, theoretical_flops
 from repro.flops.accounting import step_flops
-from repro.telemetry.counters import (Event, SimulatedDeviceBackend,
-                                      StepProfile, check_scrape_interval)
-from repro.telemetry.scrape import DeviceGrid, ScrapeSeries, scrape
+from repro.telemetry.counters import (Event, StepProfile,
+                                      check_scrape_interval)
+from repro.telemetry.scrape import DeviceGrid, ScrapeSeries
 
 
 @dataclass
@@ -172,7 +172,7 @@ def _job_draws(seed: int, sigma: float, n_dev: int):
         rng = np.random.default_rng(seed)
         stragglers = np.exp(rng.standard_normal(n_dev) * sigma)
         # seeds[0] feeds the batched engines; seeds[1 + d] device d's
-        # scalar backend
+        # per-device `SimulatedDeviceBackend`
         seeds = rng.integers(0, 2 ** 31, size=n_dev + 1)
         hit = _cache_put(_DRAW_CACHE, key, (stragglers, seeds))
     return hit
@@ -181,7 +181,7 @@ def _job_draws(seed: int, sigma: float, n_dev: int):
 def _prep_job(spec: JobSpec, max_devices: int):
     """Per-spec setup shared by every engine: §IV-C check, profile math,
     and the job's straggler/seed draws (same RNG stream on every path)."""
-    # same §IV-C policy scrape() enforces on the scalar path — all
+    # same §IV-C policy scrape() enforces on a per-device backend — both
     # engines must reject average-of-averages configs identically
     check_scrape_interval(spec.scrape_interval_s)
     prof, app, app_exact = build_profile(spec)
@@ -203,56 +203,60 @@ def _telemetry(spec: JobSpec, prof: StepProfile, app: float,
                         executed_tflops)
 
 
+def _check_engine(engine: str) -> None:
+    if engine not in ("fused", "jax"):
+        raise ValueError(f"unknown engine {engine!r} (expected 'fused' or "
+                         "'jax')")
+
+
 def simulate_job(spec: JobSpec, max_devices: int = 4, *,
-                 engine: str = "auto") -> JobTelemetry:
+                 engine: str = "fused") -> JobTelemetry:
     """Simulate the job's observable counter streams.
 
-    engine: 'vector' (default under 'auto') runs the whole device group as
-    one batched pass through repro.fleet.engine; 'jax' the same pass on
-    the jax backend (device arrays out — see repro.fleet.engine_jax);
-    'scalar' keeps the per-device, per-poll reference backend
-    (`SimulatedDeviceBackend`).  All draw from the same generative model;
-    equivalence is covered by tests/test_fleet_engine.py and
-    tests/test_engine_jax.py.
+    engine: 'fused' (default) runs the whole device group as one batched
+    NumPy pass through repro.fleet.engine, on the job's own streams;
+    'jax' the same pass on the jax backend (device arrays out — see
+    repro.fleet.engine_jax).  Both draw from the same generative model
+    as the per-device `SimulatedDeviceBackend`; equivalence is covered by
+    tests/test_fleet_engine.py and tests/test_engine_jax.py.
     """
-    from repro.fleet.engine import JobSlot, simulate_devices
+    from repro.fleet.engine import JobSlot, simulate_jobs_fused
 
+    _check_engine(engine)
     prof, app, app_exact, stragglers, seeds = _prep_job(spec, max_devices)
-    if engine in ("auto", "fused"):
-        # a single job's fused grid degenerates to the per-job batched pass
-        engine = "vector"
-    if engine == "vector":
-        grid = simulate_devices(
-            prof, duration_s=spec.duration_s,
-            interval_s=spec.scrape_interval_s, chip=spec.chip,
-            events=spec.events, stragglers=stragglers,
-            seed=int(seeds[0]))
-    elif engine == "jax":
+    slot = JobSlot(prof, spec.duration_s, spec.scrape_interval_s,
+                   events=spec.events, stragglers=stragglers, chip=spec.chip)
+    if engine == "jax":
         from repro.fleet.engine_jax import simulate_jobs_jax
-        grid = simulate_jobs_jax(
-            [JobSlot(prof, spec.duration_s, spec.scrape_interval_s,
-                     events=spec.events, stragglers=stragglers,
-                     chip=spec.chip)], seed=int(seeds[0]))[0]
-    elif engine == "scalar":
-        series = []
-        for d, straggle in enumerate(stragglers):
-            be = SimulatedDeviceBackend(
-                prof, chip=spec.chip, events=spec.events,
-                straggler_factor=float(straggle),
-                seed=int(seeds[1 + d]))
-            series.append(scrape(be, spec.duration_s,
-                                 spec.scrape_interval_s))
-        grid = DeviceGrid.from_series(series)
+        (grid,) = simulate_jobs_jax([slot], seed=int(seeds[0]))
     else:
-        raise ValueError(f"unknown engine {engine!r} (expected 'auto', "
-                         "'fused', 'jax', 'vector' or 'scalar')")
+        (grid,) = simulate_jobs_fused([slot], seed=int(seeds[0]))
     return _telemetry(spec, prof, app, app_exact, grid)
 
 
-def _simulate_fleet_fused(specs: Sequence[JobSpec], max_devices: int, *,
-                          backend: str = "numpy") -> list[JobTelemetry]:
+def simulate_fleet(specs: Sequence[JobSpec], *, max_devices: int = 4,
+                   engine: str = "fused") -> list[JobTelemetry]:
+    """Simulate a whole fleet of jobs.
+
+    engine: 'fused' (default) stacks EVERY job into padded
+    (total_devices, S_max) multi-job grids — shared RNG streams, one duty
+    evaluation and one batched OU pass per (interval, clock-model) group —
+    so the §V-B/§VI scenarios (608-job correlation sweeps, 2.5× regression
+    hunts) cost one grid pass instead of a Python loop of per-job passes.
+    'jax' runs the same fused grids on the jax backend
+    (repro.fleet.engine_jax: lax.scan OU, mesh-sharded rows, device-array
+    grids that `StreamingRollup.add_grid` reduces on-accelerator).
+
+    Reproducibility semantics: the fused grid's jitter/clock noise comes
+    from ONE stream seeded by the whole sweep, so a job's exact counter
+    realization is deterministic given (specs, order) but not a pure
+    function of its own JobSpec.seed.  To re-simulate one job of a sweep
+    bit-for-bit on its own (e.g. to bisect a regression), use
+    `simulate_job(spec)`, whose streams are per-job.
+    """
     from repro.fleet.engine import JobSlot, simulate_jobs_fused
 
+    _check_engine(engine)
     slots, meta, entropy = [], [], []
     with spans.span("fleet.prep"):
         for spec in specs:
@@ -266,46 +270,10 @@ def _simulate_fleet_fused(specs: Sequence[JobSpec], max_devices: int, *,
     # one master seed for the fused grid's shared RNG streams, derived
     # deterministically from every job's own stream
     seed = int(np.random.default_rng(entropy or [0]).integers(0, 2 ** 31))
-    if backend == "jax":
+    if engine == "jax":
         from repro.fleet.engine_jax import simulate_jobs_jax
         grids = simulate_jobs_jax(slots, seed=seed)
     else:
         grids = simulate_jobs_fused(slots, seed=seed)
     return [_telemetry(spec, prof, app, app_exact, g)
             for (spec, prof, app, app_exact), g in zip(meta, grids)]
-
-
-def simulate_fleet(specs: Sequence[JobSpec], *, max_devices: int = 4,
-                   engine: str = "auto") -> list[JobTelemetry]:
-    """Simulate a whole fleet of jobs.
-
-    engine: 'fused' (default under 'auto') stacks EVERY job into padded
-    (total_devices, S_max) multi-job grids — shared RNG streams, one duty
-    evaluation and one batched OU pass per (interval, clock-model) group —
-    so the §V-B/§VI scenarios (608-job correlation sweeps, 2.5× regression
-    hunts) cost one grid pass instead of a Python loop of per-job passes.
-    'jax' runs the same fused grids on the jax backend
-    (repro.fleet.engine_jax: lax.scan OU, mesh-sharded rows, device-array
-    grids that `StreamingRollup.add_grid` reduces on-accelerator).
-    'vector' keeps the per-job batched pass, 'scalar' the per-device
-    reference loop; all engines draw from the same generative model
-    (equivalence: tests/test_fleet_engine.py, tests/test_engine_jax.py).
-
-    Reproducibility semantics: the fused grid's jitter/clock noise comes
-    from ONE stream seeded by the whole sweep, so a job's exact counter
-    realization is deterministic given (specs, order) but not a pure
-    function of its own JobSpec.seed.  To re-simulate one job of a sweep
-    bit-for-bit on its own (e.g. to bisect a regression), use
-    engine='vector', whose streams are per-job.
-    """
-    if engine == "auto":
-        engine = "fused"
-    if engine in ("fused", "jax"):
-        return _simulate_fleet_fused(
-            specs, max_devices,
-            backend="jax" if engine == "jax" else "numpy")
-    if engine not in ("vector", "scalar"):
-        raise ValueError(f"unknown engine {engine!r} (expected 'auto', "
-                         "'fused', 'jax', 'vector' or 'scalar')")
-    return [simulate_job(s, max_devices=max_devices, engine=engine)
-            for s in specs]

@@ -130,7 +130,7 @@ def _mfu_samples(spec: JobSpec, app_mfu: float, seed: int,
     return t, np.maximum(v, 1e-3)
 
 
-def build_jobs(seed: int = 0, *, engine: str = "auto") -> list[Table3Job]:
+def build_jobs(seed: int = 0, *, engine: str = "fused") -> list[Table3Job]:
     """Simulate the whole fixture fleet (counters + MFU log streams)."""
     specs = build_specs(seed)
     tels = simulate_fleet(specs, max_devices=1, engine=engine)
@@ -157,7 +157,7 @@ def offline_rollups(jobs, *, bucket_s: float = BUCKET_S):
     return roll, mfu
 
 
-def build_fleet(seed: int = 0, *, engine: str = "auto"):
+def build_fleet(seed: int = 0, *, engine: str = "fused"):
     """Offline `JobPoint`s for `divergence.analyze` (the Fig. 5 sweep)."""
     roll, _ = offline_rollups(build_jobs(seed, engine=engine))
     return roll.to_job_points()

@@ -5,8 +5,8 @@ Each scenario is a declarative bundle: a small fleet of `JobSpec`s whose
 onset, affected jobs/devices, magnitude), plus `GroundTruthEvent` labels
 saying what a perfect detector would report.  Because faults apply to the
 FINISHED counter grid (`fleet.engine.apply_faults`), the injected ground
-truth is exactly the declared perturbation on every engine backend —
-scalar, vector, fused, and jax all replay the same labeled incident.
+truth is exactly the declared perturbation on both engines — fused and
+jax replay the same labeled incident.
 
 The library pins the paper's headline incidents and the fleet folklore
 around them:

@@ -17,10 +17,10 @@ jnp = jax.numpy
 from repro.core import spans  # noqa: E402
 from repro.fleet import JobSpec, simulate_fleet, simulate_job  # noqa: E402
 from repro.fleet.engine import (EngineParams, JobSlot,  # noqa: E402
-                                group_slots, simulate_jobs_fused)
+                                group_plan, group_slots, simulate_jobs_fused)
 from repro.fleet.engine_jax import (_group_device_sim,  # noqa: E402
-                                    _group_dims, _group_inputs,
-                                    default_mesh, simulate_jobs_jax)
+                                    _group_inputs, default_mesh,
+                                    simulate_jobs_jax)
 from repro.fleet.streaming import StreamingRollup, WindowedRollup  # noqa: E402
 from repro.kernels.fleet_hist import (_aligned_spb, _block_rows,  # noqa: E402
                                       bucket_hist_ref, ofu_bucket_hist)
@@ -133,15 +133,38 @@ def _eager_slices(slots, seed, mesh):
     rng = np.random.default_rng(seed)
     out = [None] * len(slots)
     for members in group_slots(slots).values():
-        _, strag_list, S = _group_dims(members)
+        plan = group_plan(members, EngineParams())
         args, static = _group_inputs(members, rng, EngineParams(), mesh)
         tpa, clock = _group_device_sim(*map(jnp.asarray, args), **static)
         row0 = 0
-        for (i, _, _), st, Sj in zip(members, strag_list, S):
-            nd = len(st)
+        for (i, _, _), nd, Sj in zip(members, plan.n_dev, plan.S):
             out[i] = (tpa[row0:row0 + nd, :Sj], clock[row0:row0 + nd, :Sj])
             row0 += nd
     return out
+
+
+def test_group_inputs_are_pinned_bitwise():
+    """The host arrays and static keywords one evented + plain group
+    hands the device program, frozen bit for bit (dtype, shape, bytes)."""
+    import hashlib
+    ev = (Event(300, 900, slowdown=2.5), Event(600, 700, mxu_scale=0.5))
+    slots = [JobSlot(StepProfile(0.8, 2.0), 600.0, 30.0, events=ev,
+                     stragglers=np.array([1.0, 1.3, 0.9])),
+             JobSlot(StepProfile(0.6, 2.0), 450.0, 30.0,
+                     stragglers=np.ones(2)),
+             JobSlot(StepProfile(0.5, 1.0), 900.0, 30.0, events=ev[:1])]
+    (members,) = group_slots(slots).values()
+    args, static = _group_inputs(members, np.random.default_rng(3),
+                                 EngineParams(), None)
+    assert len(args) == 11
+    h = hashlib.sha256()
+    for a in args:
+        a = np.asarray(a)
+        h.update(f"{a.dtype.str}{a.shape}".encode())
+        h.update(np.ascontiguousarray(a).tobytes())
+    h.update(repr(sorted(static.items())).encode())
+    assert h.hexdigest() == ("9df45a73c5535f09bbf6793c7dcb53d3"
+                             "53aaef6bf10dbd39c471287a09b56bef")
 
 
 def _assert_split_is_eager_slices(materialize, devices):
@@ -372,15 +395,16 @@ def test_simulate_fleet_jax_dispatch():
         assert tj.app_mfu == tr.app_mfu                  # shared profile math
         assert np.asarray(tj.grid.tpa).shape == tr.grid.tpa.shape
         assert float(tj.ofu) == pytest.approx(tr.ofu, abs=0.015)
-    with pytest.raises(ValueError, match="unknown engine"):
-        simulate_fleet(specs, engine="warp")
+    for engine in ("warp", "auto", "vector", "scalar"):
+        with pytest.raises(ValueError, match="unknown engine"):
+            simulate_fleet(specs, engine=engine)
 
 
 def test_simulate_job_jax_dispatch():
     spec = JobSpec("eq", "granite-3-2b", chips=32, true_duty=0.35,
                    duration_s=600, seed=5)
     jx = simulate_job(spec, max_devices=8, engine="jax")
-    ref = simulate_job(spec, max_devices=8, engine="vector")
+    ref = simulate_job(spec, max_devices=8, engine="fused")
     assert jx.app_mfu == ref.app_mfu
     assert float(jx.ofu) == pytest.approx(ref.ofu, abs=0.015)
     assert len(jx.device_series) == 8

@@ -2,6 +2,7 @@
 reference backend (and of the fused multi-job grid against the per-job
 loop), streaming-rollup correctness, and the fleet-scale performance
 contract (1,000 devices x 1 hour in seconds, not minutes)."""
+import hashlib
 import time
 
 import numpy as np
@@ -12,6 +13,7 @@ from repro.core.peaks import TPU_V6E_LIKE
 from repro.fleet import (JobSpec, StreamingRollup, simulate_devices,
                          simulate_fleet, simulate_job)
 from repro.fleet.engine import EngineParams, JobSlot, simulate_jobs_fused
+from repro.fleet.jobs import _job_draws, build_profile
 from repro.fleet.regression import detect_regressions
 from repro.fleet.streaming import precision_label
 from repro.telemetry import Event, SimulatedDeviceBackend, StepProfile, scrape
@@ -90,15 +92,35 @@ def test_mxu_scale_event_and_straggler_equivalence():
 
 
 def test_simulate_job_engines_agree():
+    """simulate_job against the per-device oracle: one
+    SimulatedDeviceBackend per sampled device, scraped serially, on the
+    job's own straggler draws and per-device seeds."""
     spec = JobSpec("eq", "granite-3-2b", chips=32, true_duty=0.35,
                    duration_s=600, seed=5)
-    vec = simulate_job(spec, max_devices=8, engine="vector")
-    ref = simulate_job(spec, max_devices=8, engine="scalar")
-    assert vec.app_mfu == ref.app_mfu          # profile math is shared
-    assert vec.ofu == pytest.approx(ref.ofu, abs=0.01)
-    assert len(vec.device_series) == len(ref.device_series) == 8
-    with pytest.raises(ValueError):
-        simulate_job(spec, engine="warp")
+    got = simulate_job(spec, max_devices=8)
+    prof, _, _ = build_profile(spec)
+    stragglers, seeds = _job_draws(spec.seed, spec.straggler_sigma, 8)
+    ref = [scrape(SimulatedDeviceBackend(prof, chip=spec.chip,
+                                         straggler_factor=float(s),
+                                         seed=int(seeds[1 + d])),
+                  spec.duration_s, spec.scrape_interval_s)
+           for d, s in enumerate(stragglers)]
+    assert got.grid.tpa.shape == (8, 20) == (len(ref), len(ref[0].tpa))
+    assert len(got.device_series) == 8
+    ref_tpa = np.stack([r.tpa for r in ref])
+    ref_clk = np.stack([r.clock_mhz for r in ref])
+    assert got.ofu == pytest.approx(
+        ofu_series(ref_tpa, ref_clk, spec.chip).mean(), abs=0.01)
+
+
+@pytest.mark.parametrize("engine", ["warp", "auto", "vector", "scalar"])
+def test_unknown_engine_is_rejected(engine):
+    """Only 'fused' (the NumPy reference) and 'jax' are engines."""
+    spec = JobSpec("eq", "granite-3-2b", chips=8, duration_s=300)
+    with pytest.raises(ValueError, match="unknown engine"):
+        simulate_job(spec, engine=engine)
+    with pytest.raises(ValueError, match="unknown engine"):
+        simulate_fleet([spec], engine=engine)
 
 
 # ---------------------------------------------------------------------------
@@ -117,10 +139,10 @@ def _sweep_specs(n=24):
 
 def test_fused_fleet_matches_per_job_loop():
     """Same-seed tolerance test (acceptance): the fused default must be
-    statistically indistinguishable from the per-job engine loop."""
+    statistically indistinguishable from simulating each job alone."""
     specs = _sweep_specs()
     fused = simulate_fleet(specs, max_devices=4)          # default = fused
-    perjob = simulate_fleet(specs, max_devices=4, engine="vector")
+    perjob = [simulate_job(s, max_devices=4) for s in specs]
     for f, p in zip(fused, perjob):
         assert f.app_mfu == p.app_mfu                     # shared profile math
         assert f.ofu == pytest.approx(p.ofu, abs=0.01)
@@ -138,6 +160,45 @@ def test_fused_is_the_default_and_deterministic():
         for sa, sb in zip(ta.device_series, tb.device_series):
             np.testing.assert_array_equal(sa.tpa, sb.tpa)
             np.testing.assert_array_equal(sa.clock_mhz, sb.clock_mhz)
+
+
+#: a mixed fleet for the bitwise pins: evented and plain jobs, 30 s and
+#: 15 s scrapes (two fused groups), ragged durations and stragglers
+PIN_EVENTS = (Event(300, 900, slowdown=2.5), Event(600, 700, mxu_scale=0.5))
+
+
+def _pin_specs():
+    return [JobSpec(f"p{i}", "granite-3-2b", chips=8,
+                    true_duty=0.2 + 0.05 * i,
+                    duration_s=600.0 + 300.0 * (i % 3),
+                    scrape_interval_s=15.0 if i % 2 else 30.0,
+                    events=PIN_EVENTS if i % 3 == 0 else (),
+                    straggler_sigma=0.25 if i % 4 == 1 else 0.0, seed=40 + i)
+            for i in range(6)]
+
+
+def _digest(arrays) -> str:
+    h = hashlib.sha256()
+    for a in arrays:
+        a = np.asarray(a)
+        h.update(f"{a.dtype.str}{a.shape}".encode())
+        h.update(np.ascontiguousarray(a).tobytes())
+    return h.hexdigest()
+
+
+def test_numpy_engine_grids_are_pinned_bitwise():
+    """The NumPy reference's grids, frozen bit for bit: a refactor of the
+    engine must draw the same streams in the same order and round the
+    same way (dtype, shape and bytes of every tpa and clock grid)."""
+    tels = simulate_fleet(_pin_specs(), max_devices=4)
+    assert [t.grid.tpa.shape for t in tels] == [
+        (4, 20), (4, 60), (4, 40), (4, 40), (4, 30), (4, 80)]
+    assert _digest([x for t in tels for x in (t.grid.tpa, t.grid.clock_mhz)]
+                   ) == ("e243ee03a2fe38fb5827709d7c05a8a244c2c331"
+                         "5997ac1921510373992f5b5f")
+    one = simulate_job(_pin_specs()[0], max_devices=4)
+    assert _digest([one.grid.tpa, one.grid.clock_mhz]) == (
+        "d6d0c78ecde07bc93eef94a846e95811cdea99459fdf06fb3a62a41f8f5d91d1")
 
 
 def test_fused_event_collapse_window_by_window():
@@ -189,14 +250,14 @@ def test_simulate_job_accepts_fused_and_profile_cache_not_chip_aliased():
     spec = JobSpec("one", "granite-3-2b", chips=8, true_duty=0.35,
                    duration_s=300, seed=3)
     fused = simulate_job(spec, max_devices=4, engine="fused")
-    vec = simulate_job(spec, max_devices=4, engine="vector")
-    np.testing.assert_array_equal(fused.grid.tpa, vec.grid.tpa)
+    default = simulate_job(spec, max_devices=4)
+    np.testing.assert_array_equal(fused.grid.tpa, default.grid.tpa)
     # a customized chip must not alias the stock entry in the profile
     # cache (same .name, different physics)
     slow = dataclasses.replace(spec.chip, f_max_mhz=spec.chip.f_max_mhz / 2)
     halved = simulate_job(dataclasses.replace(spec, chip=slow),
                           max_devices=4)
-    assert halved.step_time_s == pytest.approx(vec.step_time_s * 2)
+    assert halved.step_time_s == pytest.approx(fused.step_time_s * 2)
 
 
 def test_engine_params_default_not_shared():

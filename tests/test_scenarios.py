@@ -135,7 +135,7 @@ def _spec(faults=(), **kw):
                    **kw)
 
 
-@pytest.mark.parametrize("engine", ["vector", "scalar"])
+@pytest.mark.parametrize("engine", ["fused", "jax"])
 def test_posthoc_equals_apply_after_the_fact(engine):
     base = simulate_job(_spec(), engine=engine)
     faulted = simulate_job(_spec(FAULTS), engine=engine)
@@ -145,17 +145,6 @@ def test_posthoc_equals_apply_after_the_fact(engine):
     # app-side numbers are untouched: the app doesn't know it regressed
     assert faulted.app_mfu == base.app_mfu
     assert faulted.step_time_s == base.step_time_s
-
-
-def test_posthoc_jax_engine_matches_declared_perturbation():
-    jax = pytest.importorskip("jax")  # noqa: F841
-    base = simulate_job(_spec(), engine="jax")
-    faulted = simulate_job(_spec(FAULTS), engine="jax")
-    want = apply_faults(base.grid, FAULTS)
-    np.testing.assert_allclose(np.asarray(faulted.grid.tpa),
-                               np.asarray(want.tpa), rtol=1e-6)
-    np.testing.assert_allclose(np.asarray(faulted.grid.clock_mhz),
-                               np.asarray(want.clock_mhz), rtol=1e-6)
 
 
 def test_posthoc_fused_fleet_faults_only_hit_their_job():

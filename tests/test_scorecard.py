@@ -30,8 +30,7 @@ _spec = importlib.util.spec_from_file_location(
                                     "tools", "fleet_scorecard.py"))
 fleet_scorecard = importlib.util.module_from_spec(_spec)
 _spec.loader.exec_module(fleet_scorecard)
-_merge_bench_json, main = fleet_scorecard._merge_bench_json, \
-    fleet_scorecard.main
+main = fleet_scorecard.main
 
 
 @pytest.fixture(scope="module")
@@ -188,8 +187,7 @@ def test_golden_scenario_archive_is_exact():
 # ---------------------------------------------------------------------------
 # the CLI
 # ---------------------------------------------------------------------------
-def test_cli_single_scenario_exits_clean(tmp_path, monkeypatch, capsys):
-    monkeypatch.setenv("BENCH_FLEET_JSON", str(tmp_path / "bench.json"))
+def test_cli_single_scenario_exits_clean(tmp_path, capsys):
     out_json = tmp_path / "card.json"
     assert main(["--scenario", "gloo_regression_2p5x",
                  "--json", str(out_json)]) == 0
@@ -199,29 +197,3 @@ def test_cli_single_scenario_exits_clean(tmp_path, monkeypatch, capsys):
              if l.startswith("BENCH ")]
     names = {json.loads(l[6:])["name"] for l in lines}
     assert "scorecard/gloo_regression_2p5x/regression" in names
-    bench = json.loads((tmp_path / "bench.json").read_text())
-    assert {c["name"] for c in bench["cases"]} == names
-
-
-def test_bench_json_merges_by_case_name(tmp_path, monkeypatch):
-    path = tmp_path / "bench.json"
-    monkeypatch.setenv("BENCH_FLEET_JSON", str(path))
-    path.write_text(json.dumps({
-        "schema": 1, "suite": "fleet_engine",
-        "cases": [{"name": "engine/foo", "median": 1.0, "units": "ms",
-                   "metrics": {}},
-                  {"name": "scorecard/x/regression", "median": 0.5,
-                   "units": "precision", "metrics": {}}]}))
-    _merge_bench_json([{"name": "scorecard/x/regression", "median": 1.0,
-                        "units": "precision", "metrics": {}}])
-    doc = json.loads(path.read_text())
-    by_name = {c["name"]: c for c in doc["cases"]}
-    assert len(doc["cases"]) == 2                     # no duplicates
-    assert by_name["engine/foo"]["median"] == 1.0     # other suite kept
-    assert by_name["scorecard/x/regression"]["median"] == 1.0  # replaced
-    # a corrupt file is rewritten, not crashed on
-    path.write_text("{not json")
-    _merge_bench_json([{"name": "a", "median": 0, "units": "x",
-                        "metrics": {}}])
-    assert [c["name"] for c in json.loads(path.read_text())["cases"]] \
-        == ["a"]

@@ -3,13 +3,11 @@
 
 Runs `repro.scenarios.run_scorecard` over the scenario library (or a
 subset), prints one BENCH line per (scenario, detector) with precision /
-recall / time-to-detect, merges the cases into `BENCH_fleet.json`
-(alongside the engine benchmark's cases — merge is by case name, so the
-two suites coexist), and writes the full scorecard document:
+recall / time-to-detect, and writes the full scorecard document:
 
     PYTHONPATH=src python tools/fleet_scorecard.py
     PYTHONPATH=src python tools/fleet_scorecard.py \
-        --scenario gloo_regression_2p5x --engine vector --json card.json
+        --scenario gloo_regression_2p5x --engine jax --json card.json
 
 `--self-check` is the CI gate: run the whole library and fail (exit 1)
 when any pinned precision / recall / time-to-detect floor in
@@ -32,51 +30,15 @@ from repro.scenarios import (FLOORS, check_floors, run_scorecard,
                              scenario_names)
 
 
-def _bench_cases(doc: dict) -> list:
-    """Flatten the scorecard into BENCH_fleet.json case rows — one per
-    (scenario, detector), named `scorecard/<scenario>/<detector>`."""
-    cases = []
+def run(names, *, engine: str, json_path=None) -> int:
+    doc = run_scorecard(names, engine=engine)
+    # one BENCH line per (scenario, detector)
     for scen, entry in sorted(doc["scenarios"].items()):
         for det, s in sorted(entry["detectors"].items()):
-            metrics = {"precision": s["precision"], "recall": s["recall"],
-                       "ttd_s": s["ttd_s"], "n_alerts": s["n_alerts"],
-                       "n_labels": s["n_labels"]}
-            cases.append({"name": f"scorecard/{scen}/{det}",
-                          "median": s["precision"], "units": "precision",
-                          "metrics": metrics})
-    return cases
-
-
-def _merge_bench_json(cases: list) -> str:
-    """Merge scorecard cases into BENCH_fleet.json by case name, keeping
-    any cases other suites (benchmarks/fleet_engine.py) already wrote."""
-    path = os.environ.get("BENCH_FLEET_JSON", "BENCH_fleet.json")
-    doc = {"schema": 1, "suite": "fleet_engine", "cases": []}
-    if os.path.exists(path):
-        try:
-            with open(path) as f:
-                prev = json.load(f)
-            if isinstance(prev.get("cases"), list):
-                doc = prev
-        except (json.JSONDecodeError, OSError):
-            pass                 # corrupt file: rewrite from scratch
-    fresh = {c["name"] for c in cases}
-    doc["cases"] = [c for c in doc["cases"]
-                    if c.get("name") not in fresh] + cases
-    with open(path, "w") as f:
-        json.dump(doc, f, indent=2)
-        f.write("\n")
-    return path
-
-
-def run(names, *, engine: str, json_path=None, write_bench=True) -> int:
-    doc = run_scorecard(names, engine=engine)
-    cases = _bench_cases(doc)
-    for c in cases:
-        print("BENCH " + json.dumps({"name": c["name"], **c["metrics"]}))
-    if write_bench:
-        path = _merge_bench_json(cases)
-        print(f"BENCH-JSON {path} cases={len(cases)}")
+            print("BENCH " + json.dumps(
+                {"name": f"scorecard/{scen}/{det}",
+                 **{k: s[k] for k in ("precision", "recall", "ttd_s",
+                                      "n_alerts", "n_labels")}}))
     if json_path:
         with open(json_path, "w") as f:
             json.dump(doc, f, indent=2)
@@ -109,21 +71,18 @@ def main(argv=None) -> int:
                     help="score only this scenario (repeatable; "
                          "default: all)")
     ap.add_argument("--engine", default="fused",
-                    choices=["fused", "vector", "scalar", "jax"],
+                    choices=["fused", "jax"],
                     help="simulation backend (faults are post-hoc, so "
-                         "ground truth is identical on all of them)")
+                         "ground truth is identical on both)")
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write the full scorecard document here")
-    ap.add_argument("--no-bench-json", action="store_true",
-                    help="skip merging cases into BENCH_fleet.json")
     ap.add_argument("--self-check", action="store_true",
                     help="CI gate: full library, fail on any floor "
                          "violation")
     args = ap.parse_args(argv)
     if args.self_check:
         return self_check()
-    return run(args.scenario, engine=args.engine, json_path=args.json,
-               write_bench=not args.no_bench_json)
+    return run(args.scenario, engine=args.engine, json_path=args.json)
 
 
 if __name__ == "__main__":
