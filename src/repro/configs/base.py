@@ -109,7 +109,9 @@ class ModelConfig:
     norm_eps: float = 1e-5
     dtype: str = "bfloat16"
 
-    # remat policy: "nothing" | "dots" | "none"
+    # layer remat: "nothing" keeps only the attention core's output and
+    # log-sum-exp; "dots" those and every matmul without batch dims; "none"
+    # keeps every activation (no remat)
     remat: str = "nothing"
 
     def __post_init__(self):

@@ -14,6 +14,7 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -138,6 +139,13 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 # per-row log-sum-exp.  The Pallas forward in repro.kernels is validated
 # against repro.kernels.ref, which shares this math.
 # ---------------------------------------------------------------------------
+#: checkpoint names of the attention core's output and per-row log-sum-exp,
+#: the residuals its backward reads: a layer remat that saves them does not
+#: run the online-softmax forward a second time
+ATTN_OUT = "attention.out"
+ATTN_LSE = "attention.lse"
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool, q_offset=0,
                     block: int = 512, scale: Optional[float] = None,
@@ -265,6 +273,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     def attend_fwd(q, k, v, q_offset, kv_len):
         with jax.named_scope("attention.flash_fwd"):
             out, lse = forward(q, k, v, q_offset, kv_len)
+        out = checkpoint_name(out, ATTN_OUT)
+        lse = checkpoint_name(lse, ATTN_LSE)
         return out, (q, k, v, out, lse, q_offset, kv_len)
 
     def attend_bwd(res, dout):

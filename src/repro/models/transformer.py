@@ -15,7 +15,8 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models import attention as attn
 from repro.models import moe as moe_mod
-from repro.models.common import ShardCtx, constrain, dense_init, rms_norm
+from repro.models.common import (ATTN_LSE, ATTN_OUT, ShardCtx, constrain,
+                                 dense_init, rms_norm)
 
 
 # ---------------------------------------------------------------------------
@@ -108,11 +109,15 @@ def block_apply(cfg: ModelConfig, p, x, positions, ctx, *, moe: bool,
 
 
 def _remat(fn, cfg: ModelConfig):
+    """Checkpoint a layer body; every policy keeps the attention core's
+    output and log-sum-exp, so the backward never reruns its forward scan."""
     if cfg.remat == "none":
         return fn
-    policy = (jax.checkpoint_policies.nothing_saveable
-              if cfg.remat == "nothing"
-              else jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+    policies = jax.checkpoint_policies
+    policy = policies.save_only_these_names(ATTN_OUT, ATTN_LSE)
+    if cfg.remat == "dots":
+        policy = policies.save_from_both_policies(
+            policies.dots_with_no_batch_dims_saveable, policy)
     return jax.checkpoint(fn, policy=policy)
 
 
